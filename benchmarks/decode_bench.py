@@ -207,4 +207,7 @@ if __name__ == "__main__":
                     help="one small config, hard equality + speedup asserts"
                          " (the CI decode-path regression guard)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(smoke=args.smoke)

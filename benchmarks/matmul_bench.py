@@ -341,4 +341,7 @@ if __name__ == "__main__":
                     help="tiny shapes, both backends, hard exactness asserts"
                          " (the CI conversion-regression guard)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(smoke=args.smoke)

@@ -193,5 +193,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="small trace + hard asserts (CI)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for name, val, note in run(smoke=args.smoke):
         print(f"{name}: {val:.1f} tok/s  {note}")

@@ -218,19 +218,19 @@ class _Interp:
             return uni(TOP)
         if name in ("eq", "ne", "lt", "le", "gt", "ge", "is_finite"):
             return uni(Interval(0, 1))
-        if name in ("pjit", "closed_call", "core_call", "remat_call",
-                    "custom_jvp_call", "custom_vjp_call", "checkpoint",
-                    "remat2", "custom_vjp_call_jaxpr"):
-            sub = (eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-                   or eqn.params.get("fun_jaxpr"))
-            if sub is not None:
-                inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
-                outs = self.run(inner, getattr(sub, "consts", ()),
-                                ins[len(ins) - len(inner.invars):]
-                                if len(inner.invars) <= len(ins) else
-                                [TOP] * len(inner.invars))
-                return outs if len(outs) == n_out else uni(TOP)
-            return uni(TOP)
+        sub = (eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+               or eqn.params.get("fun_jaxpr"))
+        if (name not in ("scan", "while", "cond", "pallas_call")
+                and (hasattr(sub, "jaxpr") or hasattr(sub, "eqns"))):
+            # Any call-like eqn (pjit/jit, closed_call, checkpoint,
+            # custom_jvp/vjp, …) is entered by its sub-jaxpr, not by name:
+            # JAX renames these between releases (pjit → jit in 0.9).
+            inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
+            outs = self.run(inner, getattr(sub, "consts", ()),
+                            ins[len(ins) - len(inner.invars):]
+                            if len(inner.invars) <= len(ins) else
+                            [TOP] * len(inner.invars))
+            return outs if len(outs) == n_out else uni(TOP)
         if name in ("scan", "while", "cond"):
             # Loop carries would need a fixpoint; analyze the body once with
             # ⊤ carries so in-body constants still get checked, but treat the

@@ -40,7 +40,7 @@ def check_launch(M: int, K: int, N: int, C: int, blocks: Blocks, *,
                     "non-positive block extent — the grid would be empty")
     if min(bm, bn, bk) <= 0:
         return rep
-    clipped = (min(bm, M), min(bn, N), min(bk, K))
+    clipped = tune.clip_blocks((bm, bn, bk), M, K, N)
     foot = tune.vmem_footprint(clipped, C, itemsize=itemsize,
                                x_channels=x_channels, emit=emit)
     if foot > tune.VMEM_BUDGET_BYTES:
@@ -48,10 +48,13 @@ def check_launch(M: int, K: int, N: int, C: int, blocks: Blocks, *,
                 f"VMEM footprint {foot} bytes exceeds the "
                 f"{tune.VMEM_BUDGET_BYTES}-byte budget "
                 f"(x_channels={x_channels}, emit={emit})")
-    # Padding to block multiples is legal but can dominate tiny shapes.
+    # Padding to block multiples is legal but can dominate tiny shapes.  The
+    # row pad up to the sublane tile is the floor any tiling pays, so the
+    # inflation is measured against that.
     cbm, cbn, cbk = clipped
+    m_tile = -(-M // tune.SUBLANE_TILE) * tune.SUBLANE_TILE
     padded = ((M + cbm - 1) // cbm * cbm) * ((N + cbn - 1) // cbn * cbn)
-    if padded > 4 * M * N:
+    if padded > 4 * m_tile * N:
         rep.add("admissibility", f"blocks={clipped} shape=M{M}xN{N}",
                 f"padding inflates the output grid {padded / (M * N):.1f}x "
                 f"— tile the launch smaller", severity="warning")

@@ -14,6 +14,7 @@ fused kernel", "no host callbacks in the decode scan" (DESIGN.md §16).
 from __future__ import annotations
 
 import dataclasses
+import re
 from collections import Counter
 from typing import Dict, Iterable, Optional
 
@@ -22,21 +23,42 @@ from .findings import Report
 __all__ = [
     "JaxprSummary", "summarize", "summarize_fn",
     "check_resident", "check_pallas_count", "check_no_callbacks",
-    "check_reduced_wire", "MODULAR_PRIMS", "COLLECTIVE_PRIMS",
+    "check_reduced_wire", "collective_family", "MODULAR_PRIMS",
+    "COLLECTIVE_PRIMS",
 ]
 
 # Primitives that perform a modular reduction outside a kernel body — on a
 # resident path every one of these must live inside pallas_call.
 MODULAR_PRIMS = ("rem", "mod")
 
-# Cross-device collectives (repro.dist's sharded launches).  The walk
-# records each non-pallas site with its operand shapes/dtypes so the wire
-# checks and `dist.comms.collective_wire_bytes` can reason about WHAT
-# crosses the interconnect, not just that something does.
-COLLECTIVE_PRIMS = ("psum", "psum2", "all_gather", "all_to_all", "ppermute",
+# Cross-device collective FAMILIES (repro.dist's sharded launches).  JAX
+# respells these between releases and rewrites (psum → psum2 →
+# psum_invariant, all_gather → all_gather_invariant, psum_scatter), so the
+# walk matches by family and records every site under the family name,
+# with its operand shapes/dtypes — the wire checks and
+# `dist.comms.collective_wire_bytes` reason about WHAT crosses the
+# interconnect, not just that something does.
+COLLECTIVE_PRIMS = ("psum", "all_gather", "all_to_all", "ppermute",
                     "reduce_scatter", "pmax", "pmin")
 
-_CALLBACK_MARKERS = ("callback", "outside_call", "infeed", "outfeed")
+# Host-boundary primitive families: io/pure/debug callbacks, debug_print,
+# host outside-calls and in/outfeed.
+_CALLBACK_MARKERS = ("callback", "debug_print", "outside_call", "infeed",
+                     "outfeed")
+
+
+def collective_family(name: str) -> Optional[str]:
+    """The collective family a primitive name belongs to, or None."""
+    if name == "psum_scatter":
+        return "reduce_scatter"
+    for fam in COLLECTIVE_PRIMS:
+        if re.fullmatch(rf"{fam}\d*(_invariant)?", name):
+            return fam
+    return None
+
+
+def _is_callback(name: str) -> bool:
+    return any(marker in name for marker in _CALLBACK_MARKERS)
 
 
 @dataclasses.dataclass
@@ -59,8 +81,7 @@ class JaxprSummary:
 
     @property
     def callbacks(self) -> int:
-        return sum(c for n, c in self.all_prims.items()
-                   if any(marker in n for marker in _CALLBACK_MARKERS))
+        return sum(c for n, c in self.all_prims.items() if _is_callback(n))
 
     @property
     def scans(self) -> int:
@@ -89,12 +110,11 @@ def summarize(closed_jaxpr) -> JaxprSummary:
             nm = eqn.primitive.name
             if nm == "pallas_call":
                 summary.pallas_calls += 1
-            (summary.inside if inside_pallas else summary.outside)[nm] += 1
-            if not inside_pallas and nm in COLLECTIVE_PRIMS:
-                # shard_map's replication-rewrite renames psum → psum2;
-                # record the canonical name so checks match either spelling
-                canon = "psum" if nm == "psum2" else nm
-                summary.collectives.append((canon, tuple(
+            fam = collective_family(nm)
+            (summary.inside if inside_pallas else summary.outside)[
+                fam or nm] += 1
+            if not inside_pallas and fam is not None:
+                summary.collectives.append((fam, tuple(
                     (tuple(v.aval.shape), str(v.aval.dtype))
                     for v in eqn.invars if hasattr(v.aval, "shape"))))
             inner = inside_pallas or nm == "pallas_call"
@@ -159,8 +179,7 @@ def check_no_callbacks(summary: JaxprSummary, *,
     """Decode-scan invariant: no host round-trips inside the computation."""
     rep = Report(subject=f"residency:{subject}")
     bad: Dict[str, int] = {
-        n: c for n, c in summary.all_prims.items()
-        if any(marker in n for marker in _CALLBACK_MARKERS)}
+        n: c for n, c in summary.all_prims.items() if _is_callback(n)}
     if bad:
         rep.add("residency", "host boundary",
                 f"host callback primitive(s) in the jaxpr: {bad} — tokens "

@@ -102,9 +102,11 @@ def place_params(ctx: DistContext, cfg, params, *, group_basis=None):
     # model replicates instead of raising the strict "rns_tp" error.
     mode = "rns_tp_col" if ctx.layout == "column" else "rns_tp_auto"
     if spec.is_rns and spec.encode_weights:
+        # The jnp forward converter, not the spec's Pallas one: XLA cannot
+        # partition a Mosaic kernel (only a shard_map body may hold one),
+        # and integer mod gives the same residues either way.
         def enc(p):
-            return encode_params(p, backend=spec.backend,
-                                 group_basis=group_basis)
+            return encode_params(p, backend="jnp", group_basis=group_basis)
 
         shapes = jax.eval_shape(enc, params)
         out = shardings(ctx.mesh, param_specs(ctx.mesh, cfg, shapes, mode))

@@ -46,7 +46,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import multiword as mw
@@ -60,7 +59,7 @@ from repro.kernels.rns_fused import rns_fused_crt_partial, rns_fused_matmul
 from . import comms
 from .context import current
 
-__all__ = ["sharded_fused_matmul", "crt_tables", "local_plan"]
+__all__ = ["sharded_fused_matmul", "resolve_layout", "crt_tables", "local_plan"]
 
 
 # ------------------------------------------------------------ CRT tables ---
@@ -206,8 +205,9 @@ def _channel_call(ctx, x, w, basis, *, quantize, gate, srow, scol, sc,
             val = val * o["sc"]
         return val
 
-    return _isolate(shard_map(body, mesh=ctx.mesh, in_specs=(specs,),
-                              out_specs=P(), check_rep=False)(_isolate(ops)))
+    return _isolate(jax.shard_map(body, mesh=ctx.mesh, in_specs=(specs,),
+                                  out_specs=P(), check_vma=False)(
+                                      _isolate(ops)))
 
 
 def _gather_columns(res, ax, ndev):
@@ -291,8 +291,8 @@ def _column_call(ctx, x, w, basis, *, quantize, gate, emit, srow, scol, sc,
         res = out.residues if emit_res else out
         return _gather_columns(res, ax, ctx.nshards)
 
-    out = shard_map(body, mesh=ctx.mesh, in_specs=(specs,), out_specs=P(),
-                    check_rep=False)(_isolate(ops))
+    out = jax.shard_map(body, mesh=ctx.mesh, in_specs=(specs,),
+                        out_specs=P(), check_vma=False)(_isolate(ops))
     out = _isolate(out)
     if emit_res:
         return RNSTensor(residues=out, scale=out_scale, basis=basis,
@@ -301,6 +301,31 @@ def _column_call(ctx, x, w, basis, *, quantize, gate, emit, srow, scol, sc,
 
 
 # ------------------------------------------------------------- dispatch ----
+def resolve_layout(layout: str, nshards: int, basis, M: int, N: int, *,
+                   emit: str = "float") -> str:
+    """The partitioning ONE launch of ``basis`` with an (M, N) output takes
+    on a model axis of ``nshards``: "channel", "column" or "replicate".
+
+    ``layout="auto"`` asks the `comms` bytes-on-wire model; a forced layout
+    is a preference with a per-launch feasibility fallback (preferred →
+    other → replicate) on the divisibility of C resp. N."""
+    moduli = tuple(int(m) for m in basis.moduli)
+    C = len(moduli)
+    lay = layout
+    if lay == "auto":
+        _, _, nlimbs = crt_tables(basis)
+        lay = comms.choose_layout(
+            C=C, M=M, N=N, nlimbs=nlimbs, ndev=nshards, emit=emit,
+            itemsize=np.dtype(residue_dtype_for(moduli)).itemsize)
+    if lay not in ("channel", "column", "replicate"):
+        raise ValueError(f"unknown layout {lay!r}")
+    if lay == "channel" and C % nshards:
+        lay = "column" if N % nshards == 0 else "replicate"
+    elif lay == "column" and N % nshards:
+        lay = "channel" if C % nshards == 0 else "replicate"
+    return lay
+
+
 def sharded_fused_matmul(x, w, basis=None, *, ctx=None, layout=None,
                          quantize: bool = False, gate=None,
                          emit: str = "float", scale_row=None, scale_col=None,
@@ -333,25 +358,11 @@ def sharded_fused_matmul(x, w, basis=None, *, ctx=None, layout=None,
     elif basis is None:
         from repro.core.rns import basis_for_int8_matmul
         basis = basis_for_int8_matmul(np.shape(x)[-1])
-    moduli = tuple(int(m) for m in basis.moduli)
-    C = len(moduli)
-    x_shape = x.shape if isinstance(x, RNSTensor) else np.shape(x)
-    M, K = x_shape[-2], x_shape[-1]
+    M = (x.shape if isinstance(x, RNSTensor) else np.shape(x))[-2]
     N = (w.shape if isinstance(w, RNSTensor) else np.shape(w))[-1]
 
-    lay = layout or ctx.layout
-    if lay == "auto":
-        _, _, nlimbs = crt_tables(basis)
-        lay = comms.choose_layout(
-            C=C, M=M, N=N, nlimbs=nlimbs, ndev=ctx.nshards, emit=emit,
-            itemsize=np.dtype(residue_dtype_for(moduli)).itemsize)
-    if lay not in ("channel", "column", "replicate"):
-        raise ValueError(f"unknown layout {lay!r}")
-    # per-launch feasibility fallback: preferred → other → replicate
-    if lay == "channel" and C % ctx.nshards:
-        lay = "column" if N % ctx.nshards == 0 else "replicate"
-    elif lay == "column" and N % ctx.nshards:
-        lay = "channel" if C % ctx.nshards == 0 else "replicate"
+    lay = resolve_layout(layout or ctx.layout, ctx.nshards, basis, M, N,
+                         emit=emit)
     if lay == "replicate":
         return plain()
 

@@ -24,6 +24,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.channel_plan import resolve_interpret
+
 __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
@@ -116,7 +118,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     softcap: float | None = None, pad=None,
                     qpos=None, kpos=None,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """(B, H, Sq, D) × (B, H, Sk, D)² → (B, H, Sq, D).
 
     Sq may differ from Sk (decode: Sq=1 vs cached Sk); the causal frontier is
@@ -134,7 +136,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     compares the explicit coordinates; block-skip pruning is disabled
     (reachability is data-dependent).  Mutually exclusive with ``pad``;
     matches `attention_ref(qpos=..., kpos=...)`.
+
+    ``interpret=None`` resolves by device like every other kernel: native
+    on TPU, the interpreter elsewhere.
     """
+    interpret = resolve_interpret(interpret)
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     scale = 1.0 / np.sqrt(D)

@@ -1,16 +1,15 @@
 """Public jit'd entry points for the Pallas kernels.
 
 Backend dispatch: on TPU the kernels compile natively (interpret=False);
-everywhere else (this CPU container, unit tests) they run in interpret mode,
-which executes the kernel body in Python for bit-exact validation against
+everywhere else (CPU hosts, unit tests) they run in interpret mode, which
+executes the kernel body in Python for bit-exact validation against
 `ref.py`.  The device-aware selection lives in
-`core/channel_plan.resolve_interpret`; the RNS kernels resolve
-``interpret=None`` themselves, so these wrappers only coerce static
+`core/channel_plan.resolve_interpret`; every kernel resolves
+``interpret=None`` itself, so these wrappers only coerce static
 arguments.  Callers can force either mode.
 """
 from __future__ import annotations
 
-from repro.core.channel_plan import resolve_interpret
 from repro.core.conversion_plan import ConversionPlan
 
 from . import ref
@@ -52,6 +51,4 @@ def fold(x, moduli, bound, *, interpret=None, **kw):
 
 
 def flash_attention(q, k, v, *, interpret=None, **kw):
-    # flash_attention's kernel entry point does not resolve None itself.
-    return _flash_attention(q, k, v, interpret=resolve_interpret(interpret),
-                            **kw)
+    return _flash_attention(q, k, v, interpret=interpret, **kw)

@@ -23,6 +23,7 @@ __all__ = [
     "rns_matmul_ref",
     "rns_fused_matmul_ref",
     "rns_fused_chain_ref",
+    "rns_emit_ref",
     "rns_modmul_ref",
     "rns_forward_ref",
     "rns_reverse_ref",
@@ -139,6 +140,30 @@ def rns_fused_chain_ref(x, w_gate, w_up, w_down, basis, *, act=jax.nn.silu):
     a_res = cp.modmul(u_res, g_res, moduli,
                       backend="jnp").astype(plan_f.residue_dtype)
     return (matmul(a_res, wd, plan_f) * (s_up * sg)) * wd.scale
+
+
+def rns_emit_ref(x_res, w_res, basis, scale_row, scale_col):
+    """Oracle for the megakernel's ``emit="residues"`` epilogue (DESIGN.md
+    §14): canonical (C, M, K) × (C, K, N) residues → the requantized
+    product's (C, M, N) residues and its (M, 1) scale.
+
+    Staged jnp ops in the kernel's order — channel matmul + fold, MRC
+    reverse, ``clip(round(y·s_col / c), ±QMAX)`` with c = `quant.
+    requant_const(s_col, K)`, forward conversion; the scale is
+    ``s_row · c``."""
+    from repro.core.quant import QMAX, requant_const
+
+    moduli = tuple(int(m) for m in basis.moduli)
+    K, N = w_res.shape[-2], w_res.shape[-1]
+    res = rns_matmul_ref(x_res, w_res, moduli)
+    val = ConversionPlan.for_basis(basis).reverse(res, backend="jnp")
+    scol = jnp.asarray(scale_col, jnp.float32).reshape(1, N)
+    creq = requant_const(scol, K)
+    q = jnp.clip(jnp.round((val * scol) / creq), -QMAX, QMAX)
+    out = _forward_convert(q.astype(jnp.int32), moduli, backend="jnp",
+                           dtype=jnp.asarray(x_res).dtype)
+    srow = jnp.asarray(scale_row, jnp.float32).reshape(-1, 1)
+    return out, srow * creq
 
 
 def rns_modmul_ref(a_res, b_res, moduli: Sequence[int]):

@@ -45,19 +45,29 @@ __all__ = ["rns_forward", "rns_reverse"]
 
 
 # ----------------------------------------------------------------- forward --
+# Bytes of the (C, rows, L) int32 output block one forward step writes: the
+# row block is sized to this, in multiples of the int8 sublane tile.
+_FORWARD_BLOCK_BYTES = 2 * 1024 * 1024
+
+
 def _forward_kernel(mods_ref, x_ref, o_ref):
-    # (1, b) int32 broadcast against (C, 1) moduli — one VPU mod per block.
-    o_ref[...] = jnp.mod(x_ref[...], mods_ref[...])
+    # one (rows, L) block against each SMEM modulus — one VPU mod per channel
+    x = x_ref[...].astype(jnp.int32)
+    for c in range(o_ref.shape[0]):
+        o_ref[c, :, :] = jnp.mod(x, mods_ref[c])
 
 
-@functools.partial(jax.jit, static_argnames=("moduli", "block", "interpret"))
-def rns_forward(x, moduli: tuple, *, block: int = 1024,
-                interpret: bool | None = None):
+@functools.partial(jax.jit, static_argnames=("moduli", "interpret"))
+def rns_forward(x, moduli: tuple, *, interpret: bool | None = None):
     """Binary → residues: (…,) int → (C, …) canonical int32 residues.
 
     Kernel twin of ``conversion_plan.forward(backend="jnp")``; negative
     inputs map to the coset representative.  Returns int32 — callers pick the
     residue dtype (the cast is free inside the surrounding jit).
+
+    The input keeps its trailing axis as the lane axis: leading axes fold
+    into rows, which are blocked in multiples of 32 (the int8 sublane tile),
+    so a stacked (layers, K, N) weight converts without relayout.
 
     This is also the encode-time converter (`rns_tensor.encode` /
     `RNSTensor.from_int8` with ``backend="pallas"``): once a weight's
@@ -67,29 +77,29 @@ def rns_forward(x, moduli: tuple, *, block: int = 1024,
     mods = tuple(int(m) for m in moduli)
     C = len(mods)
     shape = x.shape
-    x32 = x.astype(jnp.int32).reshape(1, -1)
-    S = x32.shape[1]
-    b = max(1, min(block, S))
-    pad = (-S) % b
+    L = shape[-1] if x.ndim else 1
+    x2 = x.reshape(-1, L)
+    R = x2.shape[0]
+    rows = max(32, _FORWARD_BLOCK_BYTES // (4 * C * L) // 32 * 32)
+    b = min(rows, R)
+    pad = (-R) % b
     if pad:
-        x32 = jnp.pad(x32, ((0, 0), (0, pad)))
-    Sp = S + pad
-    table = jnp.asarray(mods, jnp.int32).reshape(C, 1)
+        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     interpret = resolve_interpret(interpret)
     out = pl.pallas_call(
         _forward_kernel,
-        grid=(Sp // b,),
+        grid=((R + pad) // b,),
         in_specs=[
-            pl.BlockSpec((C, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, b), lambda i: (0, i)),
+            pl.BlockSpec((C,), lambda i: (0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((b, L), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((C, b), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((C, Sp), jnp.int32),
+        out_specs=pl.BlockSpec((C, b, L), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((C, R + pad, L), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)) if not interpret else None,
         interpret=interpret,
-    )(table, x32)
-    return out[:, :S].reshape((C,) + shape)
+    )(jnp.asarray(mods, jnp.int32), x2)
+    return out[:, :R].reshape((C,) + shape)
 
 
 # ----------------------------------------------------------------- reverse --

@@ -43,11 +43,13 @@ float recombination and scale multiplies run in the same order) — so
 ``pallas_fused`` output is bit-identical to both staged backends on every
 golden (`tests/test_kernels.py`).
 
-Tiling is autotuned: block sizes default to `kernels/tune.blocks_for`
-(cached per-(shape, dtype, backend) sweep on device, static fallback in
-interpret mode).  The ChannelPlan fold-schedule table rides along as a tiny
-VMEM operand and the ConversionPlan moduli/inverse tables as SMEM operands,
-exactly like the staged kernels stream them.
+Tiling comes from `kernels/tune.blocks_for`: a row of the committed tune
+table, or a static default — never a sweep at trace time.  The row extent is
+padded up to the int8 sublane tile (`tune.clip_blocks`), so decode launches
+of a few rows still run tile-aligned blocks.  The ConversionPlan moduli and
+inverse tables ride along as SMEM operands; the fold rungs are baked in from
+the static plan, or — for a channel-sharded launch, whose per-device rungs
+are traced — streamed as a (C, R, 2) VMEM table.
 """
 from __future__ import annotations
 
@@ -69,11 +71,17 @@ from repro.core.rns_tensor import RNSTensor
 __all__ = ["rns_fused_matmul", "rns_fused_crt_partial"]
 
 
-def _kernel(sched_ref, mods_ref, inv_ref, *refs, plan: ChannelPlan,
-            conv: ConversionPlan, nk: int, quantize: bool, residue_in: bool,
-            has_gate: bool, emit: bool, has_srow: bool, has_scol: bool,
-            has_scale: bool, encoded: bool, crt: bool, nlimbs_out: int):
+def _kernel(*refs, plan: ChannelPlan, conv: ConversionPlan, nk: int,
+            quantize: bool, residue_in: bool, has_gate: bool, emit: bool,
+            has_srow: bool, has_scol: bool, has_scale: bool, encoded: bool,
+            crt: bool, nlimbs_out: int):
     rest = list(refs)
+    # Fold rungs: a channel-sharded (crt) launch streams its per-device
+    # rungs as a traced (C, R, 2) table; every other launch bakes the static
+    # plan's rungs into the kernel (shift/multiply by constants).
+    sched_ref = rest.pop(0) if crt else None
+    mods_ref = rest.pop(0)
+    inv_ref = rest.pop(0)
     x_ref = rest.pop(0)
     srow_ref = rest.pop(0) if has_srow else None
     gate_ref = rest.pop(0) if has_gate else None
@@ -175,8 +183,7 @@ def _kernel(sched_ref, mods_ref, inv_ref, *refs, plan: ChannelPlan,
         # of a still-negative product) as ConversionPlan's twins.
         digits = []
         for j in range(C):
-            t = plan.fold(acc_ref[j, :, :], sched=sched_ref[j, :, :],
-                          m=mods_ref[j])
+            t = plan.fold(acc_ref[j, :, :], j, m=mods_ref[j])
             mj = mods_ref[j]
             for i in range(j):
                 t = t - digits[i]
@@ -271,20 +278,22 @@ def _fused_call(x, srow, gate, w, scol, scale, creq, *, plan: ChannelPlan,
     nk = Kp // bk
     grid = (Mp // bm, Np // bn, nk)
 
-    R = plan.num_rungs
-    in_specs = [
-        pl.BlockSpec((C, R, 2), lambda i, j, k: (0, 0, 0)),
+    # mods default to the STATIC plan table; a channel-sharded launch
+    # (repro.dist) overrides it and the fold rungs with TRACED shard_map
+    # operands — the local plan is SPMD-uniform (shapes only), the actual
+    # per-device moduli and rungs arrive sliced over the mesh.
+    in_specs, args = [], []
+    if crt:
+        in_specs.append(pl.BlockSpec((C, plan.num_rungs, 2),
+                                     lambda i, j, k: (0, 0, 0)))
+        args.append(sched_tab)
+    in_specs += [
         pl.BlockSpec((C,), lambda i, j, k: (0,), memory_space=pltpu.SMEM),
         pl.BlockSpec((C, C), lambda i, j, k: (0, 0),
                      memory_space=pltpu.SMEM),
     ]
-    # sched/mods default to the STATIC plan tables; a channel-sharded launch
-    # (repro.dist) overrides them with TRACED shard_map operands — the local
-    # plan is SPMD-uniform (shapes only), the actual per-device moduli and
-    # fold rungs arrive sliced over the mesh.
-    args = [jnp.asarray(plan.sched) if sched_tab is None else sched_tab,
-            jnp.asarray(plan.mods) if mods_tab is None else mods_tab,
-            jnp.asarray(conv.inv)]
+    args += [jnp.asarray(plan.mods) if mods_tab is None else mods_tab,
+             jnp.asarray(conv.inv)]
     if residue_in:
         in_specs.append(pl.BlockSpec((C, bm, bk), lambda i, j, k: (0, i, k)))
     else:
@@ -501,11 +510,10 @@ def rns_fused_matmul(x, w, basis=None, *, quantize: bool = False,
         tbm, tbn, tbk = tune.blocks_for(M, K, N, plan.k,
                                         dtype=str(w_arr.dtype),
                                         backend=variant,
-                                        x_channels=residue_in, emit=emit_res,
-                                        interpret=interpret)
+                                        x_channels=residue_in, emit=emit_res)
         block_m, block_n, block_k = (block_m or tbm, block_n or tbn,
                                      block_k or tbk)
-    bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
+    bm, bn, bk = tune.clip_blocks((block_m, block_n, block_k), M, K, N)
 
     srow = (jnp.asarray(scale_row, jnp.float32).reshape(M, 1)
             if scale_row is not None else None)
@@ -626,11 +634,10 @@ def rns_fused_crt_partial(x, w, *, plan: ChannelPlan, conv: ConversionPlan,
     if block_m is None or block_n is None or block_k is None:
         tbm, tbn, tbk = tune.blocks_for(M, K, N, plan.k, dtype=str(w.dtype),
                                         backend=variant,
-                                        x_channels=residue_in,
-                                        interpret=interpret)
+                                        x_channels=residue_in)
         block_m, block_n, block_k = (block_m or tbm, block_n or tbn,
                                      block_k or tbk)
-    bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
+    bm, bn, bk = tune.clip_blocks((block_m, block_n, block_k), M, K, N)
 
     srow = (jnp.asarray(scale_row, jnp.float32).reshape(M, 1)
             if quantize else None)

@@ -1,26 +1,27 @@
-"""Block-size autotuner for the fused RNS megakernel (DESIGN.md §13).
+"""Block-size table for the fused RNS megakernel (DESIGN.md §13).
 
 One launch is only a win if it is also a *well-tiled* launch: the fused
 kernel holds `C` int32 accumulator planes plus both operand blocks in VMEM,
 so the best (bm, bn, bk) depends on the channel count and the shape in a way
-a single static default cannot cover.  `blocks_for` resolves the tiling:
+a single static default cannot cover.  `blocks_for` resolves the tiling from
+exactly two sources, and compiles or times nothing:
 
-  1. a persisted JSON table keyed by (backend, device, dtype, C, M, K, N) —
-     one sweep per distinct shape, ever, shared across processes and (via
-     CI caching of ``RNS_TUNE_CACHE``) across CI runs;
-  2. on a cache miss *on device* (native compile): a best-of-reps sweep over
-     the VMEM-admissible candidates, persisted;
-  3. everywhere else (the interpret path — CPU tests/CI): the static
-     fallback, clipped to the shape.  Interpret-mode timings measure the
-     Python grid loop, not the hardware, so sweeping there would poison the
-     table.
+  1. a JSON table keyed by (variant, device kind, dtype, C, M, K, N) — by
+     default the committed ``benchmarks/tune_table.json`` of this checkout
+     (``$RNS_TUNE_CACHE`` points elsewhere);
+  2. on a miss, the static default `DEFAULT_BLOCKS`, clipped to the shape
+     (`clip_blocks`) — VMEM-admissible and compiled on the chip.
+
+Sweeping is an offline step of its own: ``python -m repro.kernels.tune
+--prepopulate`` times each missing decode shape of the config zoo on the
+device it runs on and writes the winners to the table.  Engine start-up and
+kernel tracing never sweep, so a cold start compiles only what it serves.
 
 Bit-identity does not depend on the tiling (the integer stages are exact and
-the float epilogue runs per output element), so the tuner is free to pick
-any admissible candidate — it changes *when* blocks are scheduled, never
-what they compute.
+the float epilogue runs per output element), so any admissible entry is
+safe — it changes *when* blocks are scheduled, never what they compute.
 
-The sweep callable is injectable (``sweep=``) so the cache/selection logic is
+The sweep callable is injectable (``sweep=``) so the selection logic is
 unit-testable off-TPU (`tests/test_tune.py`).
 """
 from __future__ import annotations
@@ -30,8 +31,9 @@ import os
 from pathlib import Path
 from typing import Callable, Optional, Sequence, Tuple
 
-__all__ = ["DEFAULT_BLOCKS", "CANDIDATES", "DECODE_CANDIDATES", "blocks_for",
-           "cache_path", "clear_memory_cache", "vmem_footprint",
+__all__ = ["DEFAULT_BLOCKS", "CANDIDATES", "DECODE_CANDIDATES", "SUBLANE_TILE",
+           "blocks_for", "clip_blocks", "cache_path", "clear_memory_cache",
+           "vmem_footprint",
            "decode_shapes_for", "warm_for_config", "prepopulate",
            "shape_key", "parse_shape_key"]
 
@@ -55,20 +57,25 @@ CANDIDATES: Tuple[Blocks, ...] = (
 )
 
 # Decode-shape candidates: M is the batch size (a handful of rows per token
-# step), so a 128-row bm pads 8–16× dead sublanes per tile.  Small-bm tilings
-# keep the grid's M extent at 1 while still streaming MXU-aligned bn/bk —
-# `blocks_for` switches to this pool automatically for M ≤ 64 so chained
-# decode never falls back to the 128×128×512 static block.
+# step), so a 128-row bm pads 4–32× dead sublanes per tile.  bm stays at the
+# int8 sublane tile or one above it (M itself is padded up to the tile,
+# `clip_blocks`), while bn/bk stream MXU-aligned blocks — `blocks_for`
+# switches to this pool automatically for M ≤ 64.
 DECODE_CANDIDATES: Tuple[Blocks, ...] = (
-    (8, 128, 512),
-    (8, 256, 512),
-    (16, 128, 512),
-    (16, 256, 256),
     (32, 128, 512),
     (32, 256, 512),
+    (32, 128, 1024),
+    (32, 256, 256),
+    (32, 512, 512),
     (64, 128, 512),
     (64, 256, 256),
+    (64, 256, 512),
 )
+
+# Row tile every block's M extent is padded up to: int8 operands pack 32
+# rows per (32, 128) vreg tile, which also covers the f32/int32 (8, 128)
+# tiles of the scale, accumulator and output blocks.
+SUBLANE_TILE = 32
 
 # VMEM budget the candidate filter admits against (per-core VMEM is ~16 MiB;
 # leave headroom for double buffering of the streamed operands).
@@ -78,11 +85,12 @@ _MEMORY_CACHE: dict = {}
 
 
 def cache_path() -> Path:
-    """The persisted tuning table: ``$RNS_TUNE_CACHE`` or a user-cache
-    default.  CI caches this path between runs (.github/workflows/ci.yml)."""
-    return Path(os.environ.get(
-        "RNS_TUNE_CACHE",
-        os.path.join("~", ".cache", "repro-rns", "tune.json"))).expanduser()
+    """The tuning table: ``$RNS_TUNE_CACHE`` or, by default, the committed
+    ``benchmarks/tune_table.json`` of this checkout."""
+    env = os.environ.get("RNS_TUNE_CACHE")
+    if env:
+        return Path(env).expanduser()
+    return Path(__file__).resolve().parents[3] / "benchmarks" / "tune_table.json"
 
 
 def clear_memory_cache() -> None:
@@ -106,9 +114,15 @@ def vmem_footprint(blocks: Blocks, C: int, *, itemsize: int = 1,
             + C * bm * bn * 4 + out_bytes)
 
 
-def _clip(blocks: Blocks, M: int, K: int, N: int) -> Blocks:
+def clip_blocks(blocks: Blocks, M: int, K: int, N: int) -> Blocks:
+    """Clip a tiling to a launch shape.  bn/bk clip to N/K (a block equal to
+    the full dimension is always legal); bm clips to M rounded UP to the
+    `SUBLANE_TILE`, never below it — the kernel pads M to the block, so a
+    decode launch of a few rows runs one tile-aligned row block instead of
+    an int8 block whose sublane extent is below the native tile."""
     bm, bn, bk = blocks
-    return (min(bm, M), min(bn, N), min(bk, K))
+    m_tile = -(-M // SUBLANE_TILE) * SUBLANE_TILE
+    return (min(bm, m_tile), min(bn, N), min(bk, K))
 
 
 def _load_table() -> dict:
@@ -184,33 +198,52 @@ def parse_shape_key(key: str) -> dict:
     }
 
 
-def _default_sweep(M: int, K: int, N: int, C: int) -> Callable[[Blocks],
-                                                               float]:
-    """Time the real fused kernel on synthetic int8 operands (device path
-    only — `blocks_for` never calls this under interpret)."""
+def _default_sweep(M: int, K: int, N: int, C: int,
+                   backend: str = "pallas_fused") -> Callable[[Blocks], float]:
+    """Time the kernel VARIANT a table row is keyed for, on synthetic
+    operands in a C-channel basis: ``pallas_fused`` is the quantize-in,
+    encoded-weight launch of a float-domain linear; ``pallas_fused_res``
+    the residue-in launch of a chain; ``pallas_fused_res_emit`` the same
+    with the in-domain requantize epilogue.  Only `prepopulate` calls this,
+    on the device it is tuning."""
     import time
 
     import jax
     import numpy as np
     import jax.numpy as jnp
 
-    from repro.core.rns import basis_for_int8_matmul
+    from repro.core.rns import PAPER_N5_MODULI, RNSBasis
+    from repro.core.rns_tensor import encode, encode_activation
     from .rns_fused import rns_fused_matmul
 
+    if backend not in ("pallas_fused", "pallas_fused_res",
+                       "pallas_fused_res_emit"):
+        raise ValueError(f"no sweep for kernel variant {backend!r}")
+    # the C largest odd paper moduli: the basis every sizing rule
+    # (`basis_for_int8_matmul`, `basis_for_chain`) picks for C channels
+    odd = sorted((m for m in PAPER_N5_MODULI if m != 1024), reverse=True)
+    basis = RNSBasis(name=f"sweep-C{C}", moduli=tuple(odd[:C]))
     rng = np.random.default_rng(0)
-    xq = jnp.asarray(rng.integers(-127, 128, (M, K)), jnp.int8)
-    wq = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.int8)
-    basis = basis_for_int8_matmul(K)
+    xf = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
+    w = encode(jnp.asarray(rng.standard_normal((K, N)), jnp.float32), basis)
+    if backend == "pallas_fused":
+        x = xf
+        kw = dict(quantize=True, scale_row=jnp.max(jnp.abs(xf), axis=-1,
+                                                   keepdims=True) / 127.0)
+    else:
+        x = encode_activation(xf, basis)
+        kw = dict(emit="residues" if backend.endswith("_emit") else "float")
 
     def run(blocks: Blocks, reps: int = 3) -> float:
         bm, bn, bk = blocks
-        fn = lambda a, b: rns_fused_matmul(a, b, basis, block_m=bm,
-                                           block_n=bn, block_k=bk)
-        jax.block_until_ready(fn(xq, wq))            # compile
+        fn = jax.jit(lambda a, b: rns_fused_matmul(
+            a, b, scale_col=b.scale, block_m=bm, block_n=bn, block_k=bk,
+            **kw))
+        jax.block_until_ready(fn(x, w))              # compile
         best = float("inf")
         for _ in range(reps):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(xq, wq))
+            jax.block_until_ready(fn(x, w))
             best = min(best, time.perf_counter() - t0)
         return best
 
@@ -218,34 +251,30 @@ def _default_sweep(M: int, K: int, N: int, C: int) -> Callable[[Blocks],
 
 
 def blocks_for(M: int, K: int, N: int, C: int, *, dtype: str = "int8",
-               backend: str = "pallas_fused", interpret: bool | None = None,
+               backend: str = "pallas_fused",
                x_channels: bool = False, emit: bool = False,
                sweep: Optional[Callable[[Blocks], float]] = None,
                candidates: Optional[Sequence[Blocks]] = None,
                persist: bool = True) -> Blocks:
     """Resolve (block_m, block_n, block_k) for one fused-kernel shape.
 
-    Table hit → the cached choice.  Miss on device (or with an injected
-    ``sweep``) → sweep the VMEM-admissible candidates, persist the winner.
-    Miss under interpret with no injected sweep → the static fallback
-    (clipped), *without* writing the table.  ``backend`` distinguishes the
+    Table hit → the stored choice; miss → the static default.  Both are
+    clipped to the shape (`clip_blocks`), and nothing is compiled or timed.
+    Only an injected ``sweep`` (`prepopulate`, tests) times the
+    VMEM-admissible candidates on a miss and persists the winner
+    (``persist=False`` keeps it out of every table).  ``backend`` names the
     kernel *variant* ("pallas_fused", "pallas_fused_res",
-    "pallas_fused_res_emit", …) so residue-in/emit launches tune their own
-    table rows; ``x_channels``/``emit`` size the VMEM filter for them.
-    Decode shapes (M ≤ 64) sweep `DECODE_CANDIDATES` by default.
+    "pallas_fused_res_emit", …) so residue-in/emit launches own their table
+    rows; ``x_channels``/``emit`` size the VMEM filter for them.  Decode
+    shapes (M ≤ 64) sweep `DECODE_CANDIDATES` by default.
     """
-    from repro.core.channel_plan import resolve_interpret
-
     table = _load_table()
     key = _shape_key(M, K, N, C, dtype, backend)
     hit = table.get(key)
     if hit is not None:
-        return _clip(tuple(int(v) for v in hit), M, K, N)
-
+        return clip_blocks(tuple(int(v) for v in hit), M, K, N)
     if sweep is None:
-        if resolve_interpret(interpret):
-            return _clip(DEFAULT_BLOCKS, M, K, N)
-        sweep = _default_sweep(M, K, N, C)
+        return clip_blocks(DEFAULT_BLOCKS, M, K, N)
 
     if candidates is None:
         candidates = DECODE_CANDIDATES if M <= 64 else CANDIDATES
@@ -257,7 +286,7 @@ def blocks_for(M: int, K: int, N: int, C: int, *, dtype: str = "int8",
     # Clipping collapses candidates at small shapes — sweep distinct ones.
     seen, distinct = set(), []
     for c in pool:
-        cl = _clip(c, M, K, N)
+        cl = clip_blocks(c, M, K, N)
         if cl not in seen:
             seen.add(cl)
             distinct.append(cl)
@@ -336,22 +365,19 @@ def decode_shapes_for(cfg, batch_sizes=ZOO_BATCH_SIZES):
 
 def warm_for_config(cfg, batch_sizes=ZOO_BATCH_SIZES):
     """Resolve every decode shape of ``cfg`` through `blocks_for` (called by
-    `serve.Engine.__init__`): a populated table makes every lookup a hit and
-    cold-start serving pays zero on-device sweeps.  Returns a per-shape
-    report ``[{key, hit, blocks}, …]`` (empty for non-fused configs)."""
+    `serve.Engine.__init__`) and report which are table hits: a miss serves
+    the static default.  Compiles and times nothing.  Returns
+    ``[{key, hit, blocks}, …]`` (empty for non-fused configs)."""
     report = []
-    shapes = decode_shapes_for(cfg, batch_sizes)
-    if not shapes:
-        return report
     table = _load_table()
-    for s in shapes:
+    for s in decode_shapes_for(cfg, batch_sizes):
         key = _shape_key(s["M"], s["K"], s["N"], s["C"], s["dtype"],
                          s["backend"])
-        hit = key in table
         blocks = blocks_for(s["M"], s["K"], s["N"], s["C"], dtype=s["dtype"],
                             backend=s["backend"], x_channels=s["x_channels"],
                             emit=s["emit"])
-        report.append({"key": key, "hit": hit, "blocks": tuple(blocks)})
+        report.append({"key": key, "hit": key in table,
+                       "blocks": tuple(blocks)})
     return report
 
 
@@ -364,15 +390,16 @@ def _fused_archs():
 
 def prepopulate(archs=None, batch_sizes=ZOO_BATCH_SIZES) -> int:
     """Offline table prepopulation for the config zoo's decode shapes
-    (``python -m repro.kernels.tune --prepopulate``).
+    (``python -m repro.kernels.tune --prepopulate``) — the ONE place that
+    sweeps.
 
-    On device: a real best-of-reps sweep per missing shape (via
-    `blocks_for`).  Under interpret (CPU): the clipped static default is
-    written EXPLICITLY — interpret timings would poison the table, but a
-    committed entry still makes cold-start lookups hits (the key carries the
-    device kind, so a TPU runner sweeps its own rows independently).
-    Covers both the full and the smoke variant of every fused-backend arch;
-    returns the number of NEW entries written.
+    On a TPU: a best-of-reps sweep of each missing shape's own kernel
+    variant (`_default_sweep`).  Under interpret (CPU): the clipped static
+    default is written EXPLICITLY — interpret timings measure the Python
+    grid loop, not hardware, but a committed entry still makes cold-start
+    lookups hits (the key carries the device kind, so a TPU run fills its
+    own rows).  Covers both the full and the smoke variant of every
+    fused-backend arch; returns the number of NEW entries written.
     """
     from repro.configs.base import get_config, get_smoke_config
     from repro.core.channel_plan import resolve_interpret
@@ -391,12 +418,14 @@ def prepopulate(archs=None, batch_sizes=ZOO_BATCH_SIZES) -> int:
             if key in table:
                 continue
             if resolve_interpret(None):
-                best = _clip(DEFAULT_BLOCKS, s["M"], s["K"], s["N"])
-                table[key] = list(best)
+                table[key] = list(clip_blocks(DEFAULT_BLOCKS, s["M"], s["K"],
+                                              s["N"]))
             else:
                 blocks_for(s["M"], s["K"], s["N"], s["C"], dtype=s["dtype"],
                            backend=s["backend"], x_channels=s["x_channels"],
-                           emit=s["emit"])
+                           emit=s["emit"],
+                           sweep=_default_sweep(s["M"], s["K"], s["N"],
+                                                s["C"], s["backend"]))
             new += 1
     _save_table(table)
     return new
@@ -412,7 +441,7 @@ def _main(argv=None) -> int:
                          "(device: swept; interpret: static defaults)")
     ap.add_argument("--out", default=None,
                     help="table path (defaults to $RNS_TUNE_CACHE / the "
-                         "user-cache default)")
+                         "committed benchmarks/tune_table.json)")
     ap.add_argument("--archs", default=None,
                     help="comma-separated arch names (default: every "
                          "fused-backend arch in the registry)")

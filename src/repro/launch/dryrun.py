@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from repro.configs.base import SHAPES, get_config, list_archs
 from repro.launch import roofline as RL
 from repro.launch.inputs import abstract_cache, abstract_params, input_specs
-from repro.launch.mesh import dp_axes, make_production_mesh
+from repro.launch.mesh import auto_mesh, dp_axes, make_production_mesh
 from repro.launch.sharding import (batch_specs, cache_specs, logits_spec,
                                    mode_for, param_specs, shardings)
 from repro.models import transformer as T
@@ -69,9 +69,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
             # (64, 4) maps the 256 chips as 64-way data × 4-way model
             dd, mm = mesh_split
             if multi_pod:
-                mesh = jax.make_mesh((2, dd, mm), ("pod", "data", "model"))
+                mesh = auto_mesh((2, dd, mm), ("pod", "data", "model"))
             else:
-                mesh = jax.make_mesh((dd, mm), ("data", "model"))
+                mesh = auto_mesh((dd, mm), ("data", "model"))
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         mode = mode or mode_for(cfg)

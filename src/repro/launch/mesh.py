@@ -14,16 +14,25 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_host_mesh", "dp_axes", "DP_AXES",
-           "MODEL_AXIS"]
+__all__ = ["make_production_mesh", "make_host_mesh", "auto_mesh", "dp_axes",
+           "DP_AXES", "MODEL_AXIS"]
 
 MODEL_AXIS = "model"
+
+
+def auto_mesh(shape, axes):
+    """`jax.make_mesh` with every axis in Auto sharding mode.  JAX 0.9 makes
+    new meshes Explicit by default, under which the model's un-annotated ops
+    (cache writes, concatenations) reject sharded operands; this repo places
+    shardings with jit in/out specs and shard_map, which is Auto mode."""
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -38,7 +47,7 @@ def make_host_mesh(model: int = 1):
     if n % model:
         raise ValueError(f"model={model} does not divide the "
                          f"{n} available devices")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

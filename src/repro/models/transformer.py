@@ -174,6 +174,35 @@ def _attn_full(p, h, cfg: ModelConfig, window, positions):
     return o, (k, v)
 
 
+def paged_view(pool_k, pool_v, block_table, positions, shift):
+    """Each slot's attended view of a paged K/V pool.
+
+    ``pool_*``: (n_phys, block, Hk, dh); ``block_table``: (B, n_logical)
+    logical → physical block (−1 ⇒ unmapped); ``positions``: (B,) last
+    written logical position; ``shift``: (B,) view offset.  Returns
+    ``(k, v, kpos)`` with k/v (B, n_logical·block, Hk, dh): view slot s
+    holds logical position s − shift, and ``kpos`` is that position, or −1
+    where it is unmapped, negative or not yet written.  Invalid rows gather
+    trash; their masked scores contribute exact float zeros (DESIGN.md §11).
+
+    The shift places every key in the slot the static engine's left-padded
+    cache uses: an accelerator's reductions over the key axis (softmax sum,
+    p·V) group by slot, so the same keys at other slots round differently.
+    """
+    bs = pool_k.shape[1]
+    nlog = block_table.shape[1]
+    lp = (jnp.arange(nlog * bs, dtype=jnp.int32)[None]
+          - shift[:, None])                                   # (B, S)
+    phys = jnp.take_along_axis(block_table,
+                               jnp.clip(lp // bs, 0, nlog - 1), axis=1)
+    mapped = (lp >= 0) & (phys >= 0)
+    rows = jnp.where(mapped, phys * bs + lp % bs, 0)
+    k = pool_k.reshape(-1, *pool_k.shape[2:])[rows]
+    v = pool_v.reshape(-1, *pool_v.shape[2:])[rows]
+    kpos = jnp.where(mapped & (lp <= positions[:, None]), lp, -1)
+    return k, v, kpos
+
+
 def _attn_decode(p, h, cfg: ModelConfig, window, pos, cache, positions=None,
                  block_table=None):
     """One-token attention with cache update.  h: (B, 1, d).
@@ -188,7 +217,11 @@ def _attn_decode(p, h, cfg: ModelConfig, window, pos, cache, positions=None,
     the PAGED cache layout (serve/paged_cache.py, DESIGN.md §15): ``cache``
     then holds physical pools ``{"k","v"}: (n_phys, block, Hk, dh)`` shared
     by all slots, the table maps a slot's logical block to a physical block
-    (−1 ⇒ unmapped), and ``pos`` is the per-slot (B,) write position.
+    (−1 ⇒ unmapped), ``positions`` is the per-slot (B,) logical write
+    position and ``pos`` the (B,) slot of the attended view it lands in:
+    the view holds logical position r at slot ``r + pos − positions``, so a
+    slot can reproduce the static engine's left-padded cache layout key
+    for key.
     """
     B = h.shape[0]
     H, Hk, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -222,7 +255,9 @@ def _attn_decode(p, h, cfg: ModelConfig, window, pos, cache, positions=None,
         pool_k, pool_v = cache["k"], cache["v"]
         bs_blk = pool_k.shape[1]
         nlog = block_table.shape[1]
-        posv = pos if jnp.ndim(pos) else jnp.broadcast_to(pos, (B,))
+        posv = positions if positions is not None else pos
+        posv = jnp.broadcast_to(posv, (B,))
+        shift = jnp.broadcast_to(pos, (B,)) - posv
         bidx = jnp.arange(B, dtype=jnp.int32)
         # write this step's K/V at each slot's own position.  Idle/retired
         # slots (no mapped block) and positions past the mapped range are
@@ -236,17 +271,7 @@ def _attn_decode(p, h, cfg: ModelConfig, window, pos, cache, positions=None,
         off_w = posv % bs_blk
         ck = pool_k.at[phys_w, off_w].set(k[:, 0].astype(pool_k.dtype))
         cv = pool_v.at[phys_w, off_w].set(v[:, 0].astype(pool_v.dtype))
-        # gather each slot's logical view (B, nlog·block, Hk, dh); unmapped
-        # blocks gather trash and are invalidated through kpos = −1, whose
-        # masked scores contribute exact float zeros (DESIGN.md §11) — so
-        # the softmax bits match a contiguous cache of the same length.
-        btc = jnp.maximum(block_table, 0)
-        gk = ck[btc].reshape(B, nlog * bs_blk, *ck.shape[2:])
-        gv = cv[btc].reshape(B, nlog * bs_blk, *cv.shape[2:])
-        kpad = jnp.arange(nlog * bs_blk, dtype=jnp.int32)
-        mapped = block_table[:, kpad // bs_blk] >= 0           # (B, S)
-        kpos = jnp.where(mapped & (kpad[None] <= posv[:, None]),
-                         kpad[None], -1)
+        gk, gv, kpos = paged_view(ck, cv, block_table, posv, shift)
         o = attention(q, gk.astype(q.dtype), gv.astype(q.dtype), qpos, kpos,
                       window=window, softcap=cfg.softcap_attn,
                       block_kv=cfg.attn_block_kv)
@@ -532,9 +557,12 @@ def decode_step(cfg: ModelConfig, params, cache, batch, pos, positions=None,
     ragged left-padded batches (``pos − pad[i]``) — see `_attn_decode`.
 
     ``block_tables`` ((B, n_logical) int32, optional) selects the paged
-    cache layout: ``cache`` holds physical K/V pools shared across slots and
-    ``pos`` becomes the per-slot (B,) write-position vector (the
-    continuous-batching scheduler's layout, DESIGN.md §15).  SSM state stays
+    cache layout: ``cache`` holds physical K/V pools shared across slots,
+    ``positions`` is the per-slot (B,) logical write position and ``pos``
+    the (B,) slot of the attended view (``pos − positions`` shifts each
+    slot's keys into the static engine's padded layout; omitted
+    ``positions`` ⇒ no shift) — the continuous-batching scheduler's layout,
+    DESIGN.md §15.  SSM state stays
     slot-resident (O(1) per slot) and is indexed by batch row as usual.
     Returns (logits (B, vocab) f32, new_cache).
     """

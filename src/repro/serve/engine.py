@@ -96,11 +96,11 @@ class Engine:
     directly and perform zero weight quantizations / forward conversions per
     step, with greedy outputs bit-identical to the live-quantization path.
 
-    Fused-backend configs also warm the megakernel autotuner table for their
-    decode shapes at init (`kernels.tune.warm_for_config`): with the
-    committed table (`benchmarks/tune_table.json`) cold-start serving
-    performs zero on-device sweeps; ``self.tune_report`` records the
-    per-shape hits.
+    Fused-backend configs resolve the megakernel tiling of their decode
+    shapes at init (`kernels.tune.warm_for_config`): a row of the committed
+    `benchmarks/tune_table.json` (read from the checkout) or the static
+    default — init and tracing never sweep.  ``self.tune_report`` records
+    per shape whether the table hit.
     """
 
     def __init__(self, cfg: ModelConfig, params, smax: int = 2048,

@@ -23,9 +23,11 @@ frees up mid-flight:
 
 Greedy outputs are bit-identical to ``Engine.generate([prompt])`` run alone
 with ``smax == slot_tokens``: splicing strips the pad (the pool is indexed
-by *logical* position), masked gather rows contribute exact float zeros
-(DESIGN.md §11), and equal key-axis lengths keep the reduction shapes
-identical.  The first token of every request is sampled with exactly the
+by *logical* position), decode attends over a view that puts every key back
+in the slot the solo engine's left-padded cache holds it in (so the key-axis
+reductions group identically on an accelerator, not only on a sequential
+CPU), masked gather rows contribute exact float zeros (DESIGN.md §11), and
+equal key-axis lengths keep the reduction shapes identical.  The first token of every request is sampled with exactly the
 solo engine's key chain (``split(PRNGKey(seed))``), so bit-identity holds
 regardless of when the request is admitted or who its slot-mates are —
 `tests/test_scheduler.py` asserts arrival-order invariance for float and
@@ -148,12 +150,13 @@ class SlotScheduler:
     def _build_chunk_fn(self):
         cfg, chunk = self.cfg, self.decode_chunk
 
-        def run(params, cache, bt, cur, done, pos, keys, temperature, eos):
+        def run(params, cache, bt, shift, cur, done, pos, keys, temperature,
+                eos):
             def step(carry, _):
                 cur, done, cache, pos, keys = carry
                 logits, cache = T.decode_step(
-                    cfg, params, cache, {"tokens": cur[:, None]}, pos,
-                    block_tables=bt)
+                    cfg, params, cache, {"tokens": cur[:, None]}, pos + shift,
+                    positions=pos, block_tables=bt)
                 ks = jax.vmap(jax.random.split)(keys)
                 nxt = _sample_rows(logits, temperature, ks[:, 1])
                 new_done = done | (nxt == eos)
@@ -207,6 +210,12 @@ class SlotScheduler:
         batch, _ = self.engine._pack([prompt])
         pbuck = batch["tokens"].shape[1]
         pad = pbuck - plen
+        # decode attends over a view that holds logical position r at slot
+        # r + pad — the solo engine's left-padded cache layout, so the key
+        # axis reduces in the same order — wherever that layout fits the
+        # slot (the solo reference itself needs pbuck + new ≤ smax)
+        self._shift[slot] = (pad if pbuck + req.max_new_tokens
+                             <= self.slot_tokens else 0)
         with self.engine._ctx():
             logits, pf_cache, _ = self.engine._prefill(self.engine.params,
                                                        batch, smax=pbuck)
@@ -244,6 +253,7 @@ class SlotScheduler:
         for b in st.blocks:
             self._alloc.release(b)
         self._bt[slot, :] = -1
+        self._shift[slot] = 0
         self._done[slot] = True
         self._slots[slot] = None
 
@@ -274,6 +284,7 @@ class SlotScheduler:
         self._slots: List[Optional[_Slot]] = [None] * self.slots
         self._cur = np.zeros((self.slots,), np.int32)
         self._pos = np.zeros((self.slots,), np.int32)
+        self._shift = np.zeros((self.slots,), np.int32)
         self._done = np.ones((self.slots,), bool)
         self._keys = np.array(
             jnp.stack([jax.random.PRNGKey(0)] * self.slots))
@@ -304,7 +315,8 @@ class SlotScheduler:
                 cur, done, self._cache, pos, keys, toks, emit = \
                     self._chunk_fn(
                         self.engine.params, self._cache,
-                        jnp.asarray(self._bt), jnp.asarray(self._cur),
+                        jnp.asarray(self._bt), jnp.asarray(self._shift),
+                        jnp.asarray(self._cur),
                         jnp.asarray(self._done), jnp.asarray(self._pos),
                         jnp.asarray(self._keys), temp, eos)
             self._cur, self._done = np.array(cur), np.array(done)

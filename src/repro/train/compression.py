@@ -49,10 +49,8 @@ def make_compressed_allreduce(mesh, axes: Sequence[str], specs):
     specs: PartitionSpec pytree matching the input tree (the per-leaf
     layouts); the reduction happens over `axes`.
     """
-    from jax.experimental.shard_map import shard_map
-
     def body(tree):
         return compressed_psum_mean(tree, tuple(axes))
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(specs,),
-                             out_specs=specs, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                 out_specs=specs, check_vma=False))

@@ -179,3 +179,27 @@ def test_channel_decode_wire_is_reduced(mesh):
     rep = analysis.check_reduced_wire(summ, channels, nlimbs=limbs,
                                       subject="decode/channel")
     assert rep.ok, str(rep.findings)
+
+
+# ============================================ single-device, no mesh needed ==
+@pytest.mark.parametrize("layout,nshards,N,want", [
+    ("auto", 4, 576, "column"),         # smollm's C=5 on a v5e 2x2
+    ("channel", 4, 576, "column"),      # 5 % 4 → the other layout
+    ("channel", 5, 576, "channel"),
+    ("column", 4, 30, "replicate"),     # neither C nor N divides
+])
+def test_resolve_layout_per_launch_fallback(layout, nshards, N, want):
+    from repro.dist.rns_shard import resolve_layout
+
+    basis = basis_for_int8_matmul(576)
+    assert len(basis.moduli) == 5
+    assert resolve_layout(layout, nshards, basis, 4, N) == want
+
+
+def test_host_mesh_axes_are_auto():
+    """`jax.make_mesh` defaults to Explicit axes since JAX 0.9; the model's
+    un-annotated cache writes need Auto ones."""
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(model=1)
+    assert all(t == jax.sharding.AxisType.Auto for t in mesh.axis_types)

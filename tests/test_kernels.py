@@ -275,3 +275,29 @@ def test_channel_schedules_shared():
     assert sched.shape[0] == len(MODULI)
     assert (mods == np.array(MODULI)).all()
     assert 1 <= n_sub <= 3
+
+
+@pytest.mark.parametrize("case", range(5))
+def test_chip_smoke_kernel_cases_exact_in_interpret(case):
+    """`chip_smoke.py`'s kernel phase at decode rows, in interpret mode: the
+    served launch variants at smollm-135m widths equal the exact integer
+    product (float epilogues via `chip_smoke.exact_f32`, emitted residues
+    via `ref.rns_emit_ref`)."""
+    import chip_smoke
+
+    name, fn, args, check = list(chip_smoke.kernel_cases(4))[case]
+    assert check(fn(*args)) is None, name
+
+
+def test_exact_f32_is_the_kernel_float_epilogue():
+    """Above 2^24 the kernel's float32 differs from a direct conversion;
+    `exact_f32` replays its limb recombination."""
+    import chip_smoke
+    from repro.core import multiword as mw
+
+    y = np.array([0, 1, -1, 2 ** 24 + 1, -(2 ** 31 - 1), 3 * 2 ** 40 + 12345])
+    limbs = [jnp.asarray((np.abs(y) >> (15 * l)) & 0x7FFF, jnp.int32)
+             for l in range(5)]
+    mag = np.asarray(mw.limbs_to_float(limbs))
+    assert np.array_equal(chip_smoke.exact_f32(y),
+                          np.where(y < 0, -mag, mag))

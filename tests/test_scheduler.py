@@ -162,3 +162,27 @@ def test_oversized_requests_rejected_up_front():
                          n_blocks=3)
     with pytest.raises(ValueError):              # pool lifetime reservation
         tiny.serve([Request(list(range(1, fits)), fits)])
+
+
+@pytest.mark.parametrize("shift", [0, 5])
+def test_paged_view_matches_left_padded_layout(shift):
+    """Decode attends over a view of the pool that holds logical position r
+    at slot r + shift — the solo engine's left-padded cache layout, where r
+    sits at r + pad.  Identical slots keep an accelerator's key-axis
+    reductions (softmax sum, p·V) in the same order as the solo reference;
+    rows before the shift, past the last write or in unmapped blocks are
+    invalid (kpos −1)."""
+    bs, nlog, written = 4, 4, 9                     # logical 0..9 valid
+    rng = np.random.default_rng(0)
+    pool = rng.standard_normal((6, bs, 1, 2)).astype(np.float32)
+    table = np.array([[3, 1, 4, -1]], np.int32)     # logical blocks 0..2
+    k, v, kpos = T.paged_view(pool, pool, table, np.array([written]),
+                              np.array([shift]))
+    k, kpos = np.asarray(k), np.asarray(kpos)[0]
+    for s in range(nlog * bs):
+        r = s - shift
+        if 0 <= r <= written:
+            assert kpos[s] == r
+            assert np.array_equal(k[0, s], pool[table[0, r // bs], r % bs])
+        else:
+            assert kpos[s] == -1

@@ -87,3 +87,26 @@ def test_sigterm_emergency_save():
         # stopped early and saved
         assert res["last_step"] < 99
         assert ckpt.latest_step(os.path.join(d, "ckpt")) == res["last_step"]
+
+
+def test_compile_cache_placement(monkeypatch):
+    """`enable_compile_cache`: an outside JAX_COMPILATION_CACHE_DIR is left
+    to JAX; otherwise ONE fixed, git-ignored directory in the checkout."""
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, val: calls.append((name, val)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    where = compile_cache.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", where)]
+    assert where == compile_cache.enable_compile_cache()     # fixed path
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert pathlib.Path(where) == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()

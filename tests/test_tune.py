@@ -1,15 +1,20 @@
-"""Autotuner for the fused megakernel tiling: cache/selection logic.
+"""Block-size table for the fused megakernel tiling: lookup/selection logic.
 
 The sweep callable is injected, so the table behavior is fully testable on
-CPU; the interpret path must NEVER sweep (interpret timings measure the
-Python grid loop, not hardware) and must not poison the persisted table.
+CPU; a lookup without one must NEVER sweep (Engine start-up and kernel
+tracing only read the table or take the static default) and must not write
+the persisted table.
 """
 import json
 
-import numpy as np
+import pathlib
+
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro.configs.base import get_smoke_config
 from repro.kernels import tune
 
 
@@ -23,8 +28,8 @@ def tune_cache(tmp_path, monkeypatch):
 
 
 def test_interpret_fallback_is_static_and_unpersisted(tune_cache):
-    b = tune.blocks_for(64, 512, 64, 5, interpret=True)
-    assert b == tune._clip(tune.DEFAULT_BLOCKS, 64, 512, 64)
+    b = tune.blocks_for(64, 512, 64, 5)
+    assert b == tune.clip_blocks(tune.DEFAULT_BLOCKS, 64, 512, 64)
     assert not tune_cache.exists()            # no table poisoning
 
 
@@ -63,8 +68,9 @@ def test_table_hit_skips_sweep(tune_cache):
 def test_cached_entry_clips_to_smaller_shapes(tune_cache):
     tune.blocks_for(256, 1024, 256, 5, sweep=lambda b: 0.0)
     # same key namespace, tiny shape: distinct key → fallback, still clipped
-    b = tune.blocks_for(8, 32, 8, 5, interpret=True)
-    assert b == (8, 8, 32)
+    # (the row extent only down to the int8 sublane tile)
+    b = tune.blocks_for(8, 32, 8, 5)
+    assert b == (tune.SUBLANE_TILE, 8, 32)
 
 
 def test_candidates_filtered_by_vmem_budget(tune_cache):
@@ -95,8 +101,8 @@ def test_persist_false_leaks_nothing(tune_cache):
 def test_corrupt_table_recovers(tune_cache):
     tune_cache.write_text("{not json")
     tune.clear_memory_cache()
-    b = tune.blocks_for(64, 512, 64, 5, interpret=True)
-    assert b == tune._clip(tune.DEFAULT_BLOCKS, 64, 512, 64)
+    b = tune.blocks_for(64, 512, 64, 5)
+    assert b == tune.clip_blocks(tune.DEFAULT_BLOCKS, 64, 512, 64)
 
 
 def test_fused_kernel_bit_identity_across_tilings(tune_cache):
@@ -119,10 +125,60 @@ def test_fused_kernel_bit_identity_across_tilings(tune_cache):
 
 
 def _no_sweep(monkeypatch):
-    def explode(M, K, N, C):
+    def explode(M, K, N, C, backend="pallas_fused"):
         raise AssertionError(f"on-device sweep for M{M}xK{K}xN{N}/C{C} — "
                              "cold start must be table-hit only")
     monkeypatch.setattr(tune, "_default_sweep", explode)
+
+
+def test_miss_never_sweeps_even_for_native_launches(tune_cache,
+                                                     monkeypatch):
+    """Start-up/trace contract: a table miss resolves to the static default
+    without timing anything — also when the launch compiles natively (the
+    resolver used to sweep on device) — and writes no table."""
+    _no_sweep(monkeypatch)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for M in (1, 4, 8, 256):
+        b = tune.blocks_for(M, 576, 1536, 7, backend="pallas_fused_res_emit",
+                            x_channels=True, emit=True)
+        assert b == tune.clip_blocks(tune.DEFAULT_BLOCKS, M, 576, 1536)
+        assert b[0] % tune.SUBLANE_TILE == 0      # never below the tile
+    report = tune.warm_for_config(get_smoke_config(
+        "rns-smollm-135m-resident"))
+    assert report and not any(r["hit"] for r in report)
+    assert not tune_cache.exists()
+
+
+def test_default_table_is_the_committed_file(monkeypatch):
+    monkeypatch.delenv("RNS_TUNE_CACHE", raising=False)
+    committed = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+                 / "tune_table.json")
+    assert tune.cache_path() == committed
+
+
+@pytest.mark.parametrize("backend", ["pallas_fused", "pallas_fused_res",
+                                     "pallas_fused_res_emit"])
+def test_default_sweep_times_its_own_variant(backend, monkeypatch):
+    """`prepopulate`'s sweep launches the kernel variant the row is keyed
+    for (quantize-in / residue-in / residue-emit), in a C-channel basis."""
+    from repro.kernels import rns_fused
+
+    launched = []
+    real = rns_fused.rns_fused_matmul
+
+    def spy(x, w, *a, **kw):
+        launched.append((type(x).__name__, kw.get("quantize", False),
+                         kw.get("emit", "float"), len(w.moduli)))
+        return real(x, w, *a, **kw)
+
+    monkeypatch.setattr(rns_fused, "rns_fused_matmul", spy)
+    t = tune._default_sweep(4, 64, 32, 5, backend)((32, 32, 64), reps=1)
+    assert t > 0.0
+    kind, quantize, emit, C = launched[0]
+    assert C == 5
+    assert quantize == (backend == "pallas_fused")
+    assert (kind == "RNSTensor") == (backend != "pallas_fused")
+    assert emit == ("residues" if backend.endswith("_emit") else "float")
 
 
 def test_prepopulate_covers_zoo_and_is_idempotent(tune_cache, monkeypatch):

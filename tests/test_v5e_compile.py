@@ -1,0 +1,195 @@
+"""Compile the serving path's kernels for a described TPU v5e, without a chip.
+
+The TPU compiler is installed with jaxlib and compiles for a topology that
+is described, not attached.  These compiles catch what interpret mode
+cannot — blocks below the native tile, VMEM overflows, unsupported in-kernel
+ops, kernels XLA cannot place — at smollm-135m's real widths.  Nothing runs:
+a pass here says the chip's compiler accepts the program, not that its
+results are right (that is `chip_smoke.py`'s job on the chip).
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, and every test worker imports this
+file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.conversion_plan import ConversionPlan
+from repro.core.rns import basis_for_chain, basis_for_int8_matmul
+from repro.core.rns_tensor import RNSTensor
+
+D, F = 576, 1536                # smollm-135m d_model, d_ff
+DECODE_M, PREFILL_M = 4, 1024   # 4 slots; 4 lanes × the 256-token bucket
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler, or the library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # can never be read back without one — keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _native(fn, *args):
+    """Compile ``fn`` for the described chip; assert a Mosaic kernel."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _tensor(shape, basis, chip, scale=None):
+    return RNSTensor(residues=jax.ShapeDtypeStruct(shape, jnp.int8,
+                                                   sharding=chip),
+                     scale=scale, basis=basis, bound=127, signed=True)
+
+
+VARIANTS = ("quantize+encoded", "residue-in", "residue-in+gate",
+            "emit-residues")
+
+
+@pytest.mark.parametrize("M", [DECODE_M, PREFILL_M])
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_megakernel_variant_compiles_for_v5e(one_chip, variant, M):
+    from repro.kernels import rns_fused_matmul
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b5, b7 = basis_for_int8_matmul(D), basis_for_chain(F)
+    launch = functools.partial(rns_fused_matmul, interpret=False)
+    if variant == "quantize+encoded":            # float-domain linear, C=5
+        _native(lambda x, w, s, c: launch(x, w, quantize=True, scale_row=s,
+                                          scale_col=c),
+                arr((M, D)), _tensor((5, D, F), b5, one_chip),
+                arr((M, 1)), arr((1, F)))
+    elif variant == "residue-in":                # chain gate/up, C=7
+        _native(lambda x, w, c: launch(x, w, scale_col=c),
+                _tensor((7, M, D), b7, one_chip, arr((M, 1))),
+                _tensor((7, D, F), b7, one_chip), arr((1, F)))
+    elif variant == "residue-in+gate":           # chain down, C=7
+        _native(lambda x, g, w, c: launch(x, w, gate=g, scale_col=c),
+                _tensor((7, M, F), b7, one_chip, arr((M, 1))),
+                arr((M, F), jnp.int8), _tensor((7, F, D), b7, one_chip),
+                arr((1, D)))
+    else:                                        # chain up exit, C=7
+        _native(lambda x, w, c: launch(x, w, emit="residues",
+                                       scale_col=c).residues,
+                _tensor((7, M, D), b7, one_chip, arr((M, 1))),
+                _tensor((7, D, F), b7, one_chip), arr((1, F)))
+
+
+@pytest.mark.parametrize("shape", [(DECODE_M, D), (30, D, F)])
+def test_forward_conversion_compiles_for_v5e(one_chip, shape):
+    """The activation encode of a chain and the weight encode at load."""
+    from repro.kernels.rns_convert import rns_forward
+
+    moduli = basis_for_chain(F).moduli
+    _native(lambda x: rns_forward(x, moduli, interpret=False),
+            jax.ShapeDtypeStruct(shape, jnp.int8, sharding=one_chip))
+
+
+def test_reverse_conversion_compiles_for_v5e(one_chip):
+    from repro.kernels.rns_convert import rns_reverse
+
+    plan = ConversionPlan.for_basis(basis_for_chain(F))
+    _native(lambda r, s: rns_reverse(r, plan, scale=s, interpret=False),
+            jax.ShapeDtypeStruct((7, DECODE_M, D), jnp.int32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((DECODE_M, D), jnp.float32,
+                                 sharding=one_chip))
+
+
+def test_resident_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """One full-width decode step of `rns-smollm-135m-resident` (30 layers,
+    vocab 49152) from `jax.eval_shape` shapes: every kernel the step
+    launches compiles natively.  The kernels choose interpret mode by the
+    default backend, which is the CPU here, so the test points it at TPU."""
+    from repro.configs.base import get_config
+    from repro.core.rns_tensor import encode_params
+    from repro.models import transformer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("rns-smollm-135m-resident")
+
+    def params():
+        return encode_params(T.make_params(cfg, jax.random.PRNGKey(0)),
+                             backend=cfg.linear_spec.backend,
+                             group_basis={"mlp": basis_for_chain(cfg.d_ff)})
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    text = _native(
+        lambda p, c, tok, pos: T.decode_step(cfg, p, c, {"tokens": tok}, pos),
+        placed(jax.eval_shape(params)),
+        placed(jax.eval_shape(lambda: T.init_cache(cfg, DECODE_M, 512))),
+        jax.ShapeDtypeStruct((DECODE_M, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    # the layer scan body holds the stacked-QKV chain, the wo exit, the MLP
+    # chain's gate/up/down launches and the two activation encodes
+    assert text.count("tpu_custom_call") >= 5
+
+
+def test_sharded_fused_decode_step_compiles_for_v5e_2x2(topo, one_chip,
+                                                        monkeypatch):
+    """The `chip_smoke.py --four-chips` program: one decode step of
+    `rns-smollm-135m-fused` on a (1, 4) mesh of the 2x2 with
+    ``dist_layout="auto"``.  C=5 divides no axis of 4, so every launch
+    takes the column layout, and each Mosaic kernel sits inside a
+    `shard_map` — XLA refuses to partition one outside it."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs.base import get_config
+    from repro.core.rns_tensor import encode_params
+    from repro.dist.context import DistContext, use
+    from repro.launch.sharding import param_specs, shardings
+    from repro.models import transformer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("rns-smollm-135m-fused")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    rep = NamedSharding(mesh, P())
+
+    shapes = jax.eval_shape(lambda: encode_params(
+        T.make_params(cfg, jax.random.PRNGKey(0)), backend="jnp"))
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        shapes, shardings(mesh, param_specs(mesh, cfg, shapes,
+                                            "rns_tp_auto")))
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda: T.init_cache(cfg, DECODE_M, 256)))
+    with use(DistContext(mesh=mesh, layout="auto")):
+        text = _native(
+            lambda p, c, tok, pos: T.decode_step(cfg, p, c,
+                                                 {"tokens": tok}, pos),
+            params, cache,
+            jax.ShapeDtypeStruct((DECODE_M, 1), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
+    # the column layout's bitwise gather is a psum of scattered columns
+    assert "all-reduce" in text
