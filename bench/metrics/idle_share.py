@@ -1,0 +1,8 @@
+"""idle_share: the share of the traced window in which no operation ran on
+the device, in % (1 − union of busy intervals / window)."""
+
+
+def read(ctx):
+    if ctx["busy_s"] <= 0 or ctx["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx["busy_s"] / ctx["window_s"])
