@@ -16,8 +16,11 @@ ways over the SAME model/params:
 Reported per trace: sustained useful tok/s (sum of each request's own
 ``max_new_tokens`` over wall time — tokens a static group generates past a
 short request's budget are head-of-line waste, not throughput), p50/p99
-request latency in virtual decode steps (completion − arrival; the static
-engine's clock advances by each group's makespan), and peak KV cache bytes
+request latency — the scheduler's on the wall clock, from when its
+virtual clock reaches the request's arrival to the request's last token on
+the host (``stats["latency_ms_*"]``), the static engine's in virtual decode steps
+(completion − arrival; its clock advances by each group's makespan) — and
+peak KV cache bytes
 (`launch.costs.{decode_cache_bytes,paged_cache_bytes}` — validated against
 the real allocations in tests/test_costs.py).
 
@@ -148,20 +151,20 @@ def run(configs=None, smoke: bool = False):
         static_bytes = decode_cache_bytes(cfg, SLOTS, slot_tokens)
         identical = outs == solo
         static_ok = outs_static == solo
-        p50s = st["latency_steps_p50"]
-        p99s = st["latency_steps_p99"]
+        p50s = st["latency_ms_p50"]
+        p99s = st["latency_ms_p99"]
         p50t = lat_static[len(lat_static) // 2]
         p99t = lat_static[min(len(lat_static) - 1,
                               int(np.ceil(0.99 * len(lat_static))) - 1)]
         tag = f"{arch}_n{n}_L{long_new}S{short_new}"
         print(f"# {tag}: sched={tps_sched:.1f} tok/s static={tps_static:.1f} "
               f"tok/s ({tps_sched / tps_static:.2f}x)  latency p50/p99 "
-              f"sched={p50s:.0f}/{p99s:.0f} static={p50t:.0f}/{p99t:.0f} "
+              f"sched={p50s:.1f}/{p99s:.1f} ms static={p50t:.0f}/{p99t:.0f} "
               f"steps  cache {sched_bytes}B vs {static_bytes}B "
               f"({sched_bytes / static_bytes:.2f}x)  prefix_hits="
               f"{st['prefix_hits']} bit_identical={identical}")
         rows.append((f"serving_sched_{tag}", tps_sched,
-                     f"p50={p50s:.0f},p99={p99s:.0f},steps,"
+                     f"p50={p50s:.1f},p99={p99s:.1f},ms,"
                      f"cache_bytes={sched_bytes},"
                      f"prefix_hits={st['prefix_hits']},"
                      f"identical={identical}"))

@@ -70,6 +70,11 @@ from repro.core.rns_tensor import RNSTensor
 
 __all__ = ["rns_fused_matmul", "rns_fused_crt_partial"]
 
+# the `pallas_call`'s own name: a compiled program holds the kernel as the
+# custom calls `_fused_call.<n>`, the name the benchmark's roofline reader
+# matches in the device trace, whatever the Python wrapper is called
+KERNEL_NAME = "_fused_call"
+
 
 def _kernel(*refs, plan: ChannelPlan, conv: ConversionPlan, nk: int,
             quantize: bool, residue_in: bool, has_gate: bool, emit: bool,
@@ -354,6 +359,7 @@ def _fused_call(x, srow, gate, w, scol, scale, creq, *, plan: ChannelPlan,
             dimension_semantics=("parallel", "parallel",
                                  "arbitrary")) if not interpret else None,
         interpret=interpret,
+        name=KERNEL_NAME,
     )(*args)
     return out[:, :M, :N] if (emit or crt) else out[:M, :N]
 

@@ -33,6 +33,15 @@ regardless of when the request is admitted or who its slot-mates are —
 `tests/test_scheduler.py` asserts arrival-order invariance for float and
 residue-domain configs.
 
+Tracing: ``serve()`` writes host spans with `jax.profiler.TraceAnnotation`,
+which cost well under a microsecond each with no profiler running and land
+in the same trace as the device's programs when one is: ``serve.call``
+around the whole call, ``serve.admit`` (``rid=`` the request's input index)
+around each seated admission, ``serve.chunk`` around each decode chunk, and
+inside those two ``serve.upload`` (host→device puts) and ``serve.readback``
+(host reads that wait for the device).  ``stats["per_request"]`` gives each
+request's wall-clock stamps from ``serve()`` entry (PERF.md §3).
+
 Scope: full-attention and pure-SSM stacks.  Sliding-window layers keep a
 ring cache whose write cursor is shared across the batch; those
 architectures are rejected at construction and served by the static engine
@@ -41,6 +50,7 @@ architectures are rejected at construction and served by the static engine
 from __future__ import annotations
 
 import math
+import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence
@@ -48,6 +58,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as T
@@ -76,9 +87,11 @@ class _Slot:
     req: Request
     blocks: List[int]            # physical blocks held (shared ones retained)
     out: List[int]               # emitted new tokens (first included)
-    admit_step: int
-    done_step: Optional[int] = None
     finished: bool = False
+    # wall clock, seconds from serve() entry
+    seated_s: float = 0.0        # blocks reserved, admission begins
+    first_s: float = 0.0         # first token on the host
+    last_s: float = 0.0          # last token on the host
 
 
 def _sample_rows(logits, temperature, keys):
@@ -180,10 +193,11 @@ class SlotScheduler:
         return -(-(len(req.prompt) + req.max_new_tokens) // self.block_size)
 
     def _try_admit(self, req: Request, slot: int,
-                   clock: int) -> Optional[_Slot]:
-        """Reserve blocks, prefill, splice, and seat ``req`` in ``slot``.
-        Returns None (state untouched) when the pool cannot cover the
-        request's whole-lifetime reservation yet."""
+                   rid: int) -> Optional[_Slot]:
+        """Reserve blocks, prefill, splice, and seat ``req`` (input index
+        ``rid``) in ``slot``.  Returns None (state untouched, no span) when
+        the pool cannot cover the request's whole-lifetime reservation
+        yet."""
         bs, prompt = self.block_size, req.prompt
         plen = len(prompt)
         nfull = plen // bs
@@ -197,57 +211,73 @@ class SlotScheduler:
         lifetime = self._lifetime_blocks(req)
         if self._alloc.free_count < lifetime - len(shared):
             return None
-        self._alloc.prefix_hits += len(shared)
-        for b in shared:
-            self._alloc.retain(b)
-        blocks = shared + [self._alloc.alloc()
-                           for _ in range(lifetime - len(shared))]
-        self._bt[slot, :] = -1
-        self._bt[slot, :lifetime] = blocks
+        with TraceAnnotation("serve.admit", rid=rid):
+            seated_s = time.perf_counter() - self._t0
+            self._alloc.prefix_hits += len(shared)
+            for b in shared:
+                self._alloc.retain(b)
+            blocks = shared + [self._alloc.alloc()
+                               for _ in range(lifetime - len(shared))]
+            self._bt[slot, :] = -1
+            self._bt[slot, :lifetime] = blocks
 
-        # prefill alone at the bucketed length (the solo engine's own packed
-        # shape — identical pad, hence bit-identical K/V), then splice.
-        batch, _ = self.engine._pack([prompt])
-        pbuck = batch["tokens"].shape[1]
-        pad = pbuck - plen
-        # decode attends over a view that holds logical position r at slot
-        # r + pad — the solo engine's left-padded cache layout, so the key
-        # axis reduces in the same order — wherever that layout fits the
-        # slot (the solo reference itself needs pbuck + new ≤ smax)
-        self._shift[slot] = (pad if pbuck + req.max_new_tokens
-                             <= self.slot_tokens else 0)
-        with self.engine._ctx():
-            logits, pf_cache, _ = self.engine._prefill(self.engine.params,
-                                                       batch, smax=pbuck)
-        phys = np.zeros((pbuck,), np.int32)
-        offs = np.zeros((pbuck,), np.int32)
-        for s in range(pbuck):
-            lp = s - pad
-            if lp < 0 or lp // bs < len(shared):
-                continue              # pad slots / already-shared blocks → trash
-            phys[s] = blocks[lp // bs]
-            offs[s] = lp % bs
-        self._cache = splice_prefill(self._cache, pf_cache, jnp.int32(slot),
-                                     jnp.asarray(phys), jnp.asarray(offs))
-        if self.prefix_sharing:
-            for j in range(len(shared), nfull):
-                self._alloc.register(tuple(prompt[:(j + 1) * bs]), blocks[j])
+            # prefill alone at the bucketed length (the solo engine's own
+            # packed shape — identical pad, hence bit-identical K/V), then
+            # splice.
+            with TraceAnnotation("serve.upload", rid=rid):
+                batch, _ = self.engine._pack([prompt])
+            lanes, pbuck = batch["tokens"].shape
+            self._prefill_rows += lanes * pbuck
+            pad = pbuck - plen
+            # decode attends over a view that holds logical position r at
+            # slot r + pad — the solo engine's left-padded cache layout, so
+            # the key axis reduces in the same order — wherever that layout
+            # fits the slot (the solo reference itself needs pbuck + new ≤
+            # smax)
+            self._shift[slot] = (pad if pbuck + req.max_new_tokens
+                                 <= self.slot_tokens else 0)
+            with self.engine._ctx():
+                logits, pf_cache, _ = self.engine._prefill(
+                    self.engine.params, batch, smax=pbuck)
+            phys = np.zeros((pbuck,), np.int32)
+            offs = np.zeros((pbuck,), np.int32)
+            for s in range(pbuck):
+                lp = s - pad
+                if lp < 0 or lp // bs < len(shared):
+                    continue    # pad slots / already-shared blocks → trash
+                phys[s] = blocks[lp // bs]
+                offs[s] = lp % bs
+            with TraceAnnotation("serve.upload", rid=rid):
+                slot_d, phys_d, offs_d = (jnp.int32(slot),
+                                          jnp.asarray(phys),
+                                          jnp.asarray(offs))
+            self._cache = splice_prefill(self._cache, pf_cache, slot_d,
+                                         phys_d, offs_d)
+            if self.prefix_sharing:
+                for j in range(len(shared), nfull):
+                    self._alloc.register(tuple(prompt[:(j + 1) * bs]),
+                                         blocks[j])
 
-        key, first_arr = self._first_fn(logits, jnp.float32(self.temperature),
-                                        jnp.int32(req.seed))
-        first = int(first_arr[0])
-        st = _Slot(req=req, blocks=blocks, out=[first], admit_step=clock)
-        if req.max_new_tokens <= 1 or (self.eos_id is not None
-                                       and first == self.eos_id):
-            st.finished, st.done_step = True, clock
-            self._release(slot, st)
-        else:
-            self._slots[slot] = st
-            self._cur[slot] = first
-            self._pos[slot] = plen
-            self._done[slot] = False
-            self._keys[slot] = key
-        return st
+            with TraceAnnotation("serve.upload", rid=rid):
+                temp_d, seed_d = (jnp.float32(self.temperature),
+                                  jnp.int32(req.seed))
+            key, first_arr = self._first_fn(logits, temp_d, seed_d)
+            with TraceAnnotation("serve.readback", rid=rid):
+                first = int(first_arr[0])
+            st = _Slot(req=req, blocks=blocks, out=[first], seated_s=seated_s,
+                       first_s=time.perf_counter() - self._t0)
+            if req.max_new_tokens <= 1 or (self.eos_id is not None
+                                           and first == self.eos_id):
+                st.finished, st.last_s = True, st.first_s
+                self._release(slot, st)
+            else:
+                self._slots[slot] = st
+                self._cur[slot] = first
+                self._pos[slot] = plen
+                self._done[slot] = False
+                with TraceAnnotation("serve.readback", rid=rid):
+                    self._keys[slot] = key
+            return st
 
     def _release(self, slot: int, st: _Slot) -> None:
         for b in st.blocks:
@@ -262,6 +292,11 @@ class SlotScheduler:
         """Run every request to completion; returns, in INPUT order, each
         request's full token list (prompt + new tokens).  Re-entrant: pool,
         allocator, and slot state are rebuilt per call."""
+        with TraceAnnotation("serve.call"):
+            return self._serve(requests)
+
+    def _serve(self, requests: Sequence[Request]) -> List[List[int]]:
+        self._t0 = time.perf_counter()
         for r in requests:
             if len(r.prompt) + r.max_new_tokens > self.slot_tokens:
                 raise ValueError(
@@ -275,6 +310,9 @@ class SlotScheduler:
                        key=lambda i: (requests[i].arrival, i))
         pending = deque(order)
         results: List[Optional[_Slot]] = [None] * len(requests)
+        eligible_s = [0.0] * len(requests)
+        n_eligible = 0               # requests of `order` the clock reached
+        self._prefill_rows = 0
 
         self._alloc = BlockAllocator(self.n_blocks)
         self._cache = init_paged_cache(self.cfg, self.n_blocks,
@@ -294,13 +332,18 @@ class SlotScheduler:
         temp = jnp.float32(self.temperature)
         eos = jnp.int32(-1 if self.eos_id is None else self.eos_id)
         while pending or any(s is not None for s in self._slots):
+            now = time.perf_counter() - self._t0
+            while (n_eligible < len(order)
+                   and requests[order[n_eligible]].arrival <= clock):
+                eligible_s[order[n_eligible]] = now
+                n_eligible += 1
             # admit, strict arrival order, into free slots
             while pending and requests[pending[0]].arrival <= clock:
                 free = [i for i, s in enumerate(self._slots) if s is None]
                 if not free:
                     break
                 idx = pending[0]
-                st = self._try_admit(requests[idx], free[0], clock)
+                st = self._try_admit(requests[idx], free[0], idx)
                 if st is None:
                     break               # pool full: wait for a retirement
                 results[idx] = st
@@ -311,56 +354,68 @@ class SlotScheduler:
                             math.ceil(requests[pending[0]].arrival))
                 continue
 
-            with self.engine._ctx():
-                cur, done, self._cache, pos, keys, toks, emit = \
-                    self._chunk_fn(
-                        self.engine.params, self._cache,
-                        jnp.asarray(self._bt), jnp.asarray(self._shift),
-                        jnp.asarray(self._cur),
-                        jnp.asarray(self._done), jnp.asarray(self._pos),
-                        jnp.asarray(self._keys), temp, eos)
-            self._cur, self._done = np.array(cur), np.array(done)
-            self._pos, self._keys = np.array(pos), np.array(keys)
-            toks, emit = np.asarray(toks), np.asarray(emit)
+            with TraceAnnotation("serve.chunk"):
+                self._run_chunk(temp, eos)
             chunks += 1
-
-            for t in range(self.decode_chunk):
-                for i, st in enumerate(self._slots):
-                    if st is None or st.finished:
-                        continue
-                    if emit[t, i]:
-                        tok = int(toks[t, i])
-                        st.out.append(tok)
-                        hit_eos = (self.eos_id is not None
-                                   and tok == self.eos_id)
-                        if hit_eos or len(st.out) >= st.req.max_new_tokens:
-                            st.finished = True
-                            st.done_step = clock + t + 1
             clock += self.decode_chunk
-            for i, st in enumerate(self._slots):
-                if st is not None and st.finished:
-                    self._release(i, st)
 
         outs = []
-        lat = []
-        total_new = 0
+        per_request = []
         for i, r in enumerate(requests):
             st = results[i]
             outs.append(list(r.prompt) + st.out)
-            total_new += len(st.out)
-            lat.append(st.done_step - r.arrival)
-        lat = sorted(lat)
+            per_request.append({"eligible_s": eligible_s[i],
+                                "seated_s": st.seated_s,
+                                "first_s": st.first_s, "last_s": st.last_s,
+                                "new_tokens": len(st.out)})
+        lat = sorted(1e3 * (p["last_s"] - p["eligible_s"])
+                     for p in per_request)
         self.stats = {
             "requests": len(requests),
-            "new_tokens": total_new,
+            "new_tokens": sum(p["new_tokens"] for p in per_request),
+            "prompt_tokens": sum(len(r.prompt) for r in requests),
+            "prefill_rows": self._prefill_rows,
             "chunks": chunks,
             "steps": clock,
             "pool_bytes": pool_bytes,
             "peak_blocks": self._alloc.peak_used,
             "prefix_hits": self._alloc.prefix_hits,
-            "latency_steps_p50": lat[len(lat) // 2] if lat else 0.0,
-            "latency_steps_p99": lat[min(len(lat) - 1,
-                                         math.ceil(0.99 * len(lat)) - 1)]
+            # eligible → last token on the host, wall clock
+            "latency_ms_p50": lat[len(lat) // 2] if lat else 0.0,
+            "latency_ms_p99": lat[min(len(lat) - 1,
+                                      math.ceil(0.99 * len(lat)) - 1)]
             if lat else 0.0,
+            "per_request": per_request,
         }
         return outs
+
+    def _run_chunk(self, temp, eos) -> None:
+        """One decode chunk: upload the slot state, run the chunk program,
+        read its tokens back, emit them and retire finished requests."""
+        with TraceAnnotation("serve.upload"):
+            args = (jnp.asarray(self._bt), jnp.asarray(self._shift),
+                    jnp.asarray(self._cur), jnp.asarray(self._done),
+                    jnp.asarray(self._pos), jnp.asarray(self._keys))
+        with self.engine._ctx():
+            cur, done, self._cache, pos, keys, toks, emit = self._chunk_fn(
+                self.engine.params, self._cache, *args, temp, eos)
+        with TraceAnnotation("serve.readback"):
+            self._cur, self._done = np.array(cur), np.array(done)
+            self._pos, self._keys = np.array(pos), np.array(keys)
+            toks, emit = np.asarray(toks), np.asarray(emit)
+        now = time.perf_counter() - self._t0
+
+        for t in range(self.decode_chunk):
+            for i, st in enumerate(self._slots):
+                if st is None or st.finished:
+                    continue
+                if emit[t, i]:
+                    tok = int(toks[t, i])
+                    st.out.append(tok)
+                    hit_eos = (self.eos_id is not None
+                               and tok == self.eos_id)
+                    if hit_eos or len(st.out) >= st.req.max_new_tokens:
+                        st.finished, st.last_s = True, now
+        for i, st in enumerate(self._slots):
+            if st is not None and st.finished:
+                self._release(i, st)

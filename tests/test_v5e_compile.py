@@ -12,6 +12,7 @@ one process may hold the TPU library, and every test worker imports this
 file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,11 @@ def _tensor(shape, basis, chip, scale=None):
 
 VARIANTS = ("quantize+encoded", "residue-in", "residue-in+gate",
             "emit-residues")
+# the megakernel's custom call as a v5e trace names it: the name
+# `bench/metrics/megakernel_roofline.py` matches, pinned by the
+# pallas_call's `name=` whatever the Python wrapper is called
+KERNEL_CALL = re.compile(r"^\s*(ROOT )?%_fused_call\.\d+ = .*custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', re.M)
 
 
 @pytest.mark.parametrize("M", [DECODE_M, PREFILL_M])
@@ -79,24 +85,25 @@ def test_megakernel_variant_compiles_for_v5e(one_chip, variant, M):
     b5, b7 = basis_for_int8_matmul(D), basis_for_chain(F)
     launch = functools.partial(rns_fused_matmul, interpret=False)
     if variant == "quantize+encoded":            # float-domain linear, C=5
-        _native(lambda x, w, s, c: launch(x, w, quantize=True, scale_row=s,
-                                          scale_col=c),
-                arr((M, D)), _tensor((5, D, F), b5, one_chip),
-                arr((M, 1)), arr((1, F)))
+        text = _native(lambda x, w, s, c: launch(x, w, quantize=True,
+                                                 scale_row=s, scale_col=c),
+                       arr((M, D)), _tensor((5, D, F), b5, one_chip),
+                       arr((M, 1)), arr((1, F)))
     elif variant == "residue-in":                # chain gate/up, C=7
-        _native(lambda x, w, c: launch(x, w, scale_col=c),
-                _tensor((7, M, D), b7, one_chip, arr((M, 1))),
-                _tensor((7, D, F), b7, one_chip), arr((1, F)))
+        text = _native(lambda x, w, c: launch(x, w, scale_col=c),
+                       _tensor((7, M, D), b7, one_chip, arr((M, 1))),
+                       _tensor((7, D, F), b7, one_chip), arr((1, F)))
     elif variant == "residue-in+gate":           # chain down, C=7
-        _native(lambda x, g, w, c: launch(x, w, gate=g, scale_col=c),
-                _tensor((7, M, F), b7, one_chip, arr((M, 1))),
-                arr((M, F), jnp.int8), _tensor((7, F, D), b7, one_chip),
-                arr((1, D)))
+        text = _native(lambda x, g, w, c: launch(x, w, gate=g, scale_col=c),
+                       _tensor((7, M, F), b7, one_chip, arr((M, 1))),
+                       arr((M, F), jnp.int8),
+                       _tensor((7, F, D), b7, one_chip), arr((1, D)))
     else:                                        # chain up exit, C=7
-        _native(lambda x, w, c: launch(x, w, emit="residues",
-                                       scale_col=c).residues,
-                _tensor((7, M, D), b7, one_chip, arr((M, 1))),
-                _tensor((7, D, F), b7, one_chip), arr((1, F)))
+        text = _native(lambda x, w, c: launch(x, w, emit="residues",
+                                              scale_col=c).residues,
+                       _tensor((7, M, D), b7, one_chip, arr((M, 1))),
+                       _tensor((7, D, F), b7, one_chip), arr((1, F)))
+    assert KERNEL_CALL.search(text)
 
 
 @pytest.mark.parametrize("shape", [(DECODE_M, D), (30, D, F)])
