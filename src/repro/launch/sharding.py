@@ -225,11 +225,9 @@ def cache_specs(mesh, cfg: ModelConfig, cache_tree, *, paged: bool = False):
     """Decode caches: KV sequence-sharded over "model", SSM state-sharded.
 
     ``paged=True`` reads the tree as `serve.paged_cache`'s pool layout —
-    k/v leaves are (L, n_phys, block_size, Hk, dh), the SAME rank as a
-    stacked dense cache, so the dense rule would sequence-shard the
-    block_size axis (breaking the pool's physical-block indexing).  Paged
-    pools shard the independent physical-block axis instead and keep block
-    contents whole.
+    one ``kv`` leaf (L, n_phys, block_size, 2·Hk·dh) a column.  Paged pools
+    shard the independent physical-block axis and keep block contents
+    whole.
     """
     dp = dp_axes(mesh)
     mdl = MODEL_AXIS
@@ -238,10 +236,10 @@ def cache_specs(mesh, cfg: ModelConfig, cache_tree, *, paged: bool = False):
         p = _path_str(path)
         shape = leaf.shape
         name = p.rsplit("/", 1)[-1]
-        if paged and name in ("k", "v") and len(shape) == 5:
-            # (L, n_phys, block_size, Hk, dh): blocks are independent rows
+        if paged and name == "kv":
+            # (L, n_phys, block_size, 2·Hk·dh): blocks are independent
             # (the trash block rides along); never split inside a block
-            return P(None, _maybe(mesh, dp, shape[1]), None, None, None)
+            return P(None, _maybe(mesh, dp, shape[1]), None, None)
         # stacked: (L, B, S, Hk, dh); per_block: (B, S, Hk, dh)
         stacked = shape and len(shape) in (5,) and name in ("k", "v")
         if name in ("k", "v"):

@@ -22,7 +22,7 @@ Three entry points per model, matching the dry-run cells:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -174,6 +174,21 @@ def _attn_full(p, h, cfg: ModelConfig, window, positions):
     return o, (k, v)
 
 
+def _paged_rows(block_table, positions, shift, block: int):
+    """Pool rows ``phys·block + offset`` of each slot's attended view (0,
+    a trash row, where unmapped) and their key positions (−1 ⇒ invalid):
+    `paged_view`'s index rule."""
+    nlog = block_table.shape[1]
+    lp = (jnp.arange(nlog * block, dtype=jnp.int32)[None]
+          - shift[:, None])                                   # (B, S)
+    phys = jnp.take_along_axis(block_table,
+                               jnp.clip(lp // block, 0, nlog - 1), axis=1)
+    mapped = (lp >= 0) & (phys >= 0)
+    rows = jnp.where(mapped, phys * block + lp % block, 0)
+    kpos = jnp.where(mapped & (lp <= positions[:, None]), lp, -1)
+    return rows, kpos
+
+
 def paged_view(pool_k, pool_v, block_table, positions, shift):
     """Each slot's attended view of a paged K/V pool.
 
@@ -189,22 +204,52 @@ def paged_view(pool_k, pool_v, block_table, positions, shift):
     cache uses: an accelerator's reductions over the key axis (softmax sum,
     p·V) group by slot, so the same keys at other slots round differently.
     """
-    bs = pool_k.shape[1]
-    nlog = block_table.shape[1]
-    lp = (jnp.arange(nlog * bs, dtype=jnp.int32)[None]
-          - shift[:, None])                                   # (B, S)
-    phys = jnp.take_along_axis(block_table,
-                               jnp.clip(lp // bs, 0, nlog - 1), axis=1)
-    mapped = (lp >= 0) & (phys >= 0)
-    rows = jnp.where(mapped, phys * bs + lp % bs, 0)
+    rows, kpos = _paged_rows(block_table, positions, shift, pool_k.shape[1])
     k = pool_k.reshape(-1, *pool_k.shape[2:])[rows]
     v = pool_v.reshape(-1, *pool_v.shape[2:])[rows]
-    kpos = jnp.where(mapped & (lp <= positions[:, None]), lp, -1)
     return k, v, kpos
 
 
+def _kv_rows(k, v):
+    """The paged pool's rows: each token's K then its V, ``(…, Hk, dh)`` →
+    ``(…, 2·Hk·dh)`` (`serve.paged_cache.init_paged_cache`)."""
+    return jnp.concatenate([k.reshape(*k.shape[:-2], -1),
+                            v.reshape(*v.shape[:-2], -1)], -1)
+
+
+class _PagedStep(NamedTuple):
+    """One paged decode step's indices, shared by every layer (see
+    `_paged_step`)."""
+    rows: jax.Array      # (B, S) pool row of each view slot
+    kpos: jax.Array      # (B, S) key position, −1 invalid
+    own: jax.Array       # (B, S) the view slot that takes the step's K/V
+    write: jax.Array     # (B,) pool row the step's K/V go to
+
+
+def _paged_step(block_table, pos, positions, block: int) -> _PagedStep:
+    """The indices of a paged decode step: `paged_view`'s rows and key
+    positions, and where each slot's new K/V go in the view and the pool."""
+    B, nlog = block_table.shape
+    posv = jnp.broadcast_to(positions if positions is not None else pos,
+                            (B,))
+    pos = jnp.broadcast_to(pos, (B,))
+    rows, kpos = _paged_rows(block_table, posv, pos - posv, block)
+    # idle/retired slots (no mapped block) and positions past the mapped
+    # range write to the reserved trash block 0, which is never read
+    # unmasked — a frozen `done` slot can keep "writing" harmlessly.
+    blk = posv // block
+    phys = jnp.where(blk < nlog,
+                     block_table[jnp.arange(B), jnp.minimum(blk, nlog - 1)],
+                     -1)
+    # a mapped write lands at view slot posv + shift = pos
+    own = ((jnp.arange(nlog * block, dtype=jnp.int32)[None] == pos[:, None])
+           & (phys >= 0)[:, None])
+    return _PagedStep(rows, kpos, own,
+                      jnp.maximum(phys, 0) * block + posv % block)
+
+
 def _attn_decode(p, h, cfg: ModelConfig, window, pos, cache, positions=None,
-                 block_table=None):
+                 paged=None):
     """One-token attention with cache update.  h: (B, 1, d).
 
     ``pos`` is the scalar cache-slot index (padded coordinate: slot s holds
@@ -213,15 +258,18 @@ def _attn_decode(p, h, cfg: ModelConfig, window, pos, cache, positions=None,
     batches — they drive RoPE and the attention mask, so a short prompt's
     RoPE phases and window are not shifted by its batchmates' padding.
 
-    ``block_table`` ((B, n_logical) int32, optional) switches the layer to
+    ``paged`` (``(_PagedStep, layer)``, optional) switches the layer to
     the PAGED cache layout (serve/paged_cache.py, DESIGN.md §15): ``cache``
-    then holds physical pools ``{"k","v"}: (n_phys, block, Hk, dh)`` shared
-    by all slots, the table maps a slot's logical block to a physical block
-    (−1 ⇒ unmapped), ``positions`` is the per-slot (B,) logical write
-    position and ``pos`` the (B,) slot of the attended view it lands in:
-    the view holds logical position r at slot ``r + pos − positions``, so a
-    slot can reproduce the static engine's left-padded cache layout key
-    for key.
+    then holds the stacked physical pool ``{"kv": (L, n_phys·block,
+    2·Hk·dh)}`` shared by all slots, one token's K then V a row, read-only
+    here: the layer gathers its view from layer ``layer`` of it, puts its
+    own new K/V at the view slot the step writes, and returns only that
+    row ``{"kv": (B, 2·Hk·dh)}`` — `decode_step` scatters every layer's
+    rows into the pool after the layer scan.  ``positions`` is the
+    per-slot (B,) logical write position and ``pos`` the (B,) slot of the
+    attended view it lands in: the view holds logical position r at slot
+    ``r + pos − positions``, so a slot can reproduce the static engine's
+    left-padded cache layout key for key.
     """
     B = h.shape[0]
     H, Hk, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -245,40 +293,29 @@ def _attn_decode(p, h, cfg: ModelConfig, window, pos, cache, positions=None,
         cos, sin = rope(qpos, dh, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    if block_table is not None:
+    if paged is not None:
         if "pos" in cache:
             raise ValueError(
                 "paged decode does not support ring (SWA) caches: the ring's "
                 "cache_pos is one (W,) vector shared across the batch, so "
                 "per-slot write positions have nowhere to live — serve "
                 "SWA/hybrid-SWA architectures through the static engine")
-        pool_k, pool_v = cache["k"], cache["v"]
-        bs_blk = pool_k.shape[1]
-        nlog = block_table.shape[1]
-        posv = positions if positions is not None else pos
-        posv = jnp.broadcast_to(posv, (B,))
-        shift = jnp.broadcast_to(pos, (B,)) - posv
-        bidx = jnp.arange(B, dtype=jnp.int32)
-        # write this step's K/V at each slot's own position.  Idle/retired
-        # slots (no mapped block) and positions past the mapped range are
-        # routed to the reserved trash block 0, which is never read
-        # unmasked — a frozen `done` slot can keep "writing" harmlessly.
-        blk_idx = posv // bs_blk
-        phys_w = jnp.where(blk_idx < nlog,
-                           block_table[bidx, jnp.minimum(blk_idx, nlog - 1)],
-                           0)
-        phys_w = jnp.maximum(phys_w, 0)
-        off_w = posv % bs_blk
-        ck = pool_k.at[phys_w, off_w].set(k[:, 0].astype(pool_k.dtype))
-        cv = pool_v.at[phys_w, off_w].set(v[:, 0].astype(pool_v.dtype))
-        gk, gv, kpos = paged_view(ck, cv, block_table, posv, shift)
-        o = attention(q, gk.astype(q.dtype), gv.astype(q.dtype), qpos, kpos,
-                      window=window, softcap=cfg.softcap_attn,
+        step, layer = paged
+        pool = cache["kv"]                  # (L, n_phys·block, 2·Hk·dh)
+        new = _kv_rows(k, v).astype(pool.dtype)            # (B, 1, 2·Hk·dh)
+        # the view the write-then-gather order reads, key for key, with no
+        # copy of the pool: the gather reads the step's incoming pool and
+        # the slot's own row comes from this step's K/V
+        kv = jnp.where(step.own[..., None], new, pool[layer, step.rows])
+        gk = kv[..., :Hk * dh].reshape(B, -1, Hk, dh)
+        gv = kv[..., Hk * dh:].reshape(B, -1, Hk, dh)
+        o = attention(q, gk.astype(q.dtype), gv.astype(q.dtype), qpos,
+                      step.kpos, window=window, softcap=cfg.softcap_attn,
                       block_kv=cfg.attn_block_kv)
         o = linear(o.reshape(B, 1, H * dh), p["attn"]["wo"], cfg.linear_spec)
         if cfg.post_norm:
             o = rms_norm(o, p["norm_mix_post"], cfg.norm_eps)
-        return o, {"k": ck, "v": cv}
+        return o, {"kv": new[:, 0]}
     if jnp.ndim(pos):
         raise ValueError("per-slot (B,) decode positions need block_table "
                          "paging; the contiguous cache layout shares one "
@@ -514,12 +551,11 @@ def _cache_is_stacked(cache_col) -> bool:
 
 # -------------------------------------------------------------- decode step -
 def _layer_decode(p, h, cfg: ModelConfig, block_layer, window, pos, cache,
-                  positions=None, block_table=None):
+                  positions=None, paged=None):
     kind = _mixer_kind(cfg)
     new_cache = {}
     if kind == "attn":
-        o, nc = _attn_decode(p, h, cfg, window, pos, cache, positions,
-                             block_table)
+        o, nc = _attn_decode(p, h, cfg, window, pos, cache, positions, paged)
         new_cache.update(nc)
         h = h + o
     elif kind == "ssm":
@@ -532,7 +568,7 @@ def _layer_decode(p, h, cfg: ModelConfig, block_layer, window, pos, cache,
     else:
         oa, nc = _attn_decode(p, h, cfg, window, pos,
                               {k: v for k, v in cache.items() if k != "ssm"},
-                              positions, block_table)
+                              positions, paged)
         x = rms_norm(h, p["norm_mix"], cfg.norm_eps)
         os_, ns = ssm_decode_step(p["ssm"], x, cache["ssm"], cfg)
         new_cache.update(nc)
@@ -581,6 +617,9 @@ def decode_step(cfg: ModelConfig, params, cache, batch, pos, positions=None,
         h = h + pe.astype(h.dtype)
 
     windows = jnp.asarray(window_array(cfg, FULL_WINDOW))
+    if block_tables is not None:
+        return _paged_decode(cfg, params, h, windows, cache, pos, positions,
+                             block_tables)
     all_stacked = all(_cache_is_stacked(cache[f"sub{i}"])
                       for i in range(cfg.layers_per_block))
     if all_stacked:
@@ -590,8 +629,7 @@ def decode_step(cfg: ModelConfig, params, cache, batch, pos, positions=None,
             new_rows = {}
             for i in range(cfg.layers_per_block):
                 hh, nc = _layer_decode(blk[f"sub{i}"], hh, cfg, i, wrow[i],
-                                       pos, crow[f"sub{i}"], positions,
-                                       block_tables)
+                                       pos, crow[f"sub{i}"], positions)
                 new_rows[f"sub{i}"] = nc
             return hh, new_rows
 
@@ -610,8 +648,7 @@ def decode_step(cfg: ModelConfig, params, cache, batch, pos, positions=None,
                 c = col["per_block"][b] if not _cache_is_stacked(col) \
                     else jax.tree.map(lambda x: x[b], col)
                 h, nc = _layer_decode(blk[f"sub{i}"], h, cfg, i,
-                                      windows[b, i], pos, c, positions,
-                                      block_tables)
+                                      windows[b, i], pos, c, positions)
                 new_caches[f"sub{i}"]["per_block"].append(nc)
         for i in range(cfg.layers_per_block):
             col = cache[f"sub{i}"]
@@ -620,6 +657,52 @@ def decode_step(cfg: ModelConfig, params, cache, batch, pos, positions=None,
                     lambda *xs: jnp.stack(xs, 0),
                     *new_caches[f"sub{i}"]["per_block"])
 
+    logits = _lm_head(cfg, params, h)[:, 0]
+    return logits, new_caches
+
+
+def _paged_decode(cfg: ModelConfig, params, h, windows, cache, pos, positions,
+                  block_tables):
+    """`decode_step` over the paged pool (DESIGN.md §15).
+
+    The K/V pool stays out of the layer scan's carried state: every layer
+    reads the step's incoming pool (a gather that takes the layer's index,
+    so no layer's pool is sliced out or copied) and gives back only its
+    new rows; ONE scatter after the scan writes all layers' rows, in place
+    where the caller donates the pool.  SSM leaves ride the scan as
+    before.
+    """
+    subs = [f"sub{i}" for i in range(cfg.layers_per_block)]
+    pools = {s: cache[s]["kv"] for s in subs if "kv" in cache[s]}
+    step = None                 # pure SSM: the block table is unused
+    if pools:
+        block = next(iter(pools.values())).shape[2]
+        step = _paged_step(block_tables, pos, positions, block)
+    # (L, n_phys, block, 2·Hk·dh) as (L, n_phys·block, 2·Hk·dh) rows: a
+    # bitcast on the chip where a block fills whole tiles (block 16, bf16)
+    flat = {s: p.reshape(p.shape[0], -1, p.shape[-1])
+            for s, p in pools.items()}
+
+    def body(carry, xs):
+        hh = carry
+        blk, wrow, crow, layer = xs
+        new_rows = {}
+        for i, sub in enumerate(subs):
+            c = dict(crow[sub], **({"kv": flat[sub]} if sub in flat else {}))
+            hh, nc = _layer_decode(blk[sub], hh, cfg, i, wrow[i], pos, c,
+                                   positions, (step, layer))
+            new_rows[sub] = nc
+        return hh, new_rows
+
+    rest = {s: {n: x for n, x in cache[s].items() if n != "kv"}
+            for s in subs}
+    h, new_caches = jax.lax.scan(
+        body, h, (params["blocks"], windows, rest,
+                  jnp.arange(cfg.n_blocks, dtype=jnp.int32)))
+    layers = jnp.arange(cfg.n_blocks, dtype=jnp.int32)[:, None]
+    for sub, pool in flat.items():
+        new_caches[sub]["kv"] = pool.at[layers, step.write[None]].set(
+            new_caches[sub]["kv"]).reshape(pools[sub].shape)
     logits = _lm_head(cfg, params, h)[:, 0]
     return logits, new_caches
 

@@ -12,8 +12,9 @@ the aggregate *live* tokens, not the reservation.  Three pieces:
     by construction — decode only ever writes a slot's own *private* tail
     and decode blocks, never a shared full block);
   * :func:`init_paged_cache` — the device pool pytree, mirroring
-    `models.transformer.init_cache` leaf structure except that attention
-    K/V leaves are pools ``(n_blocks_layers, n_phys, block, Hk, dh)``.
+    `models.transformer.init_cache` leaf structure except that the
+    attention K/V leaves are one pool ``kv: (n_blocks_layers, n_phys,
+    block, 2·Hk·dh)``, a token's K then its V in one row (DESIGN.md §15).
     Physical block 0 is reserved as the *trash* block: idle slots and
     out-of-range writes land there and it is never read unmasked.  SSM
     state/conv stay slot-resident (they are O(1) per slot — there is
@@ -37,7 +38,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.layers import dtype_of
 from repro.models.ssm import init_ssm_cache
-from repro.models.transformer import FULL_WINDOW, _mixer_kind
+from repro.models.transformer import FULL_WINDOW, _kv_rows, _mixer_kind
 
 __all__ = ["BlockAllocator", "init_paged_cache", "splice_prefill",
            "paged_cache_nbytes"]
@@ -107,33 +108,37 @@ def init_paged_cache(cfg: ModelConfig, n_phys: int, block_size: int,
     """Zeroed paged decode cache: pooled K/V + slot-resident SSM state.
 
     Leaf structure mirrors `transformer.init_cache` (``sub{i}`` columns
-    stacked over blocks) so `decode_step`'s scan-over-layers consumes it
-    unchanged; only the attention leaves change shape —
-    ``(n_blocks_layers, n_phys, block_size, Hk, dh)`` pools instead of
-    ``(…, B, smax, …)`` reservations.
+    stacked over blocks); the attention leaves ``k``/``v`` become one pool
+    ``kv: (n_blocks_layers, n_phys, block_size, 2·Hk·dh)`` instead of
+    ``(…, B, smax, …)`` reservations.  One row holds a token's K then its
+    V, so a row is a whole number of the chip's 128 lanes (smollm-135m:
+    2 × 3 × 64 = 384) and the TPU keeps the pool as rows, untransposed and
+    unpadded: the decode step then gathers and scatters whole rows with no
+    relayout of the pool (DESIGN.md §15).
     """
     dtype = dtype_of(cfg)
     Hk, dh = cfg.num_kv_heads, cfg.head_dim
     kind = _mixer_kind(cfg)
+    L = cfg.n_blocks
     out = {}
     for i in range(cfg.layers_per_block):
-        per_block = []
-        for b in range(cfg.n_blocks):
-            layer = b * cfg.layers_per_block + i
-            leaf = {}
-            if kind in ("attn", "hybrid"):
+        col = {}
+        if kind in ("attn", "hybrid"):
+            for b in range(L):
+                layer = b * cfg.layers_per_block + i
                 if cfg.window_for_layer(layer, FULL_WINDOW) < FULL_WINDOW:
                     raise ValueError(
                         f"{cfg.name}: layer {layer} uses a sliding-window "
                         "ring cache — paged decode supports full-attention "
                         "and pure-SSM stacks only (DESIGN.md §15)")
-                leaf["k"] = jnp.zeros((n_phys, block_size, Hk, dh), dtype)
-                leaf["v"] = jnp.zeros((n_phys, block_size, Hk, dh), dtype)
-            if kind in ("ssm", "hybrid"):
-                leaf["ssm"] = init_ssm_cache(cfg, slots, dtype)
-            per_block.append(leaf)
-        out[f"sub{i}"] = jax.tree.map(lambda *xs: jnp.stack(xs, 0),
-                                      *per_block)
+            # the stacked pool in one allocation: per-layer zeros and a
+            # stack would hold the pool two and three times over
+            col["kv"] = jnp.zeros((L, n_phys, block_size, 2 * Hk * dh), dtype)
+        if kind in ("ssm", "hybrid"):
+            col["ssm"] = jax.tree.map(
+                lambda x: jnp.zeros((L, *x.shape), x.dtype),
+                jax.eval_shape(lambda: init_ssm_cache(cfg, slots, dtype)))
+        out[f"sub{i}"] = col
     return out
 
 
@@ -161,9 +166,15 @@ def splice_prefill(cache, pf_cache, slot, phys, offs):
     out = {}
     for sub, col in cache.items():
         new = dict(col)
-        if "k" in col:
-            new["k"] = col["k"].at[:, phys, offs].set(pf_cache[sub]["k"][:, 0])
-            new["v"] = col["v"].at[:, phys, offs].set(pf_cache[sub]["v"][:, 0])
+        if "kv" in col:
+            # as rows (L, n_phys·block, 2·Hk·dh), indexed like the decode
+            # step's write, so the scatter updates the pool in place
+            L, _, block, width = col["kv"].shape
+            layers = jnp.arange(L, dtype=jnp.int32)[:, None]
+            new["kv"] = col["kv"].reshape(L, -1, width).at[
+                layers, (phys * block + offs)[None]].set(
+                _kv_rows(pf_cache[sub]["k"][:, 0], pf_cache[sub]["v"][:, 0])
+            ).reshape(col["kv"].shape)
         if "ssm" in col:
             new["ssm"] = jax.tree.map(
                 lambda dst, src: dst.at[:, slot].set(src[:, 0]),

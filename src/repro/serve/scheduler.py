@@ -105,6 +105,35 @@ def _sample_rows(logits, temperature, keys):
     return jnp.where(t > 0.0, sampled.astype(jnp.int32), greedy)
 
 
+def chunk_program(cfg: ModelConfig, chunk: int):
+    """The jitted decode chunk: ``chunk`` paged decode steps with on-device
+    sampling, over the slot state and the donated K/V pool."""
+
+    def run(params, cache, bt, shift, cur, done, pos, keys, temperature, eos):
+        def step(carry, _):
+            cur, done, cache, pos, keys = carry
+            logits, cache = T.decode_step(
+                cfg, params, cache, {"tokens": cur[:, None]}, pos + shift,
+                positions=pos, block_tables=bt)
+            ks = jax.vmap(jax.random.split)(keys)
+            nxt = _sample_rows(logits, temperature, ks[:, 1])
+            new_done = done | (nxt == eos)
+            # freeze a finished lane's position: its junk steps keep
+            # overwriting ONE private row instead of marching into
+            # unmapped (trash-routed) territory; either way nothing
+            # emitted past `done` is read.
+            pos = jnp.where(new_done, pos, pos + 1)
+            return (nxt, new_done, cache, pos, ks[:, 0]), (nxt, ~done)
+
+        (cur, done, cache, pos, keys), (toks, emit) = jax.lax.scan(
+            step, (cur, done, cache, pos, keys), None, length=chunk)
+        return cur, done, cache, pos, keys, toks, emit
+
+    # the pool is donated: the scheduler rebinds it every chunk, so XLA
+    # updates the K/V blocks in place instead of copying the pool.
+    return jax.jit(run, donate_argnums=(1,))
+
+
 class SlotScheduler:
     """Continuous-batching scheduler: ``slots`` resident decode lanes over a
     paged KV pool of ``n_blocks × block_size`` token rows.
@@ -147,7 +176,7 @@ class SlotScheduler:
                              mesh=mesh, dist_layout=dist_layout)
         # fail fast on ring-cache architectures (and validate pool shapes)
         init_paged_cache(cfg, 2, block_size, 1)
-        self._chunk_fn = self._build_chunk_fn()
+        self._chunk_fn = chunk_program(cfg, self.decode_chunk)
 
         # admission-time first token, one dispatch: the solo engine's exact
         # chain — split(PRNGKey(seed)), sample with the sub-key, carry the
@@ -158,35 +187,6 @@ class SlotScheduler:
 
         self._first_fn = jax.jit(_first)
         self.stats: Dict[str, Any] = {}
-
-    # ----------------------------------------------------------- device step -
-    def _build_chunk_fn(self):
-        cfg, chunk = self.cfg, self.decode_chunk
-
-        def run(params, cache, bt, shift, cur, done, pos, keys, temperature,
-                eos):
-            def step(carry, _):
-                cur, done, cache, pos, keys = carry
-                logits, cache = T.decode_step(
-                    cfg, params, cache, {"tokens": cur[:, None]}, pos + shift,
-                    positions=pos, block_tables=bt)
-                ks = jax.vmap(jax.random.split)(keys)
-                nxt = _sample_rows(logits, temperature, ks[:, 1])
-                new_done = done | (nxt == eos)
-                # freeze a finished lane's position: its junk steps keep
-                # overwriting ONE private row instead of marching into
-                # unmapped (trash-routed) territory; either way nothing
-                # emitted past `done` is read.
-                pos = jnp.where(new_done, pos, pos + 1)
-                return (nxt, new_done, cache, pos, ks[:, 0]), (nxt, ~done)
-
-            (cur, done, cache, pos, keys), (toks, emit) = jax.lax.scan(
-                step, (cur, done, cache, pos, keys), None, length=chunk)
-            return cur, done, cache, pos, keys, toks, emit
-
-        # the pool is donated: the scheduler rebinds it every chunk, so XLA
-        # updates the K/V blocks in place instead of copying the pool.
-        return jax.jit(run, donate_argnums=(1,))
 
     # ------------------------------------------------------------- admission -
     def _lifetime_blocks(self, req: Request) -> int:
@@ -315,6 +315,7 @@ class SlotScheduler:
         self._prefill_rows = 0
 
         self._alloc = BlockAllocator(self.n_blocks)
+        self._cache = None          # the last call's pool, freed first
         self._cache = init_paged_cache(self.cfg, self.n_blocks,
                                        self.block_size, self.slots)
         pool_bytes = paged_cache_nbytes(self._cache)

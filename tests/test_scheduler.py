@@ -15,6 +15,7 @@ the scheduler's OWN engine (``lanes == slots`` pins the decode batch width;
 XLA reduction order is shape-dependent, see serve/engine.py).
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -186,3 +187,70 @@ def test_paged_view_matches_left_padded_layout(shift):
             assert np.array_equal(k[0, s], pool[table[0, r // bs], r % bs])
         else:
             assert kpos[s] == -1
+
+
+def test_paged_decode_step_writes_only_the_steps_rows():
+    """One paged decode step with two live slots (one shifted), a retired
+    slot (no mapped block) and a slot whose write position falls in an
+    unmapped block.  The pool comes back changed only at the live slots'
+    (layer, block, row) write rows and in trash block 0; those rows hold the
+    step's K/V, and the live slots' logits are bit-identical to the
+    write-then-gather order: the step's K/V written into the pool first,
+    then every layer attending over `paged_view` of the written pool (the
+    contiguous decode path over that view)."""
+    from repro.serve.paged_cache import init_paged_cache
+
+    cfg = get_smoke_config("smollm-135m")
+    params = T.make_params(cfg, jax.random.PRNGKey(0))
+    nlog, n_phys, L = 6, 20, cfg.n_blocks
+    Hk, dh = cfg.num_kv_heads, cfg.head_dim
+    rng = np.random.default_rng(3)
+    pool = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        init_paged_cache(cfg, n_phys, BLOCK, 4))
+    table = np.full((4, nlog), -1, np.int32)
+    table[0, :5] = [3, 7, 1, 12, 9]     # live: writes position 17
+    table[1, :4] = [5, 2, 18, 11]       # live, view shifted by 4: writes 13
+    table[3, :2] = [4, 6]               # position 13's block is unmapped
+    positions = np.array([17, 13, 9, 13], np.int32)   # slot 2: retired
+    shift = np.array([0, 4, 8, 4], np.int32)          # view slot 17 for all
+    live = [0, 1]
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (4, 1)), jnp.int32)
+
+    logits, out = T.decode_step(cfg, params, pool, {"tokens": tokens},
+                                jnp.asarray(positions + shift),
+                                positions=jnp.asarray(positions),
+                                block_tables=jnp.asarray(table))
+
+    def kv(pool):                       # (L, n_phys, block, 2, Hk, dh)
+        return np.asarray(pool["sub0"]["kv"], np.float32).reshape(
+            L, n_phys, BLOCK, 2, Hk, dh)
+
+    def contiguous(pool):               # each layer's paged_view, stacked
+        views = [T.paged_view(*(jnp.asarray(kv(pool)[i, :, :, j])
+                                for j in range(2)),
+                              jnp.asarray(table), jnp.asarray(positions),
+                              jnp.asarray(shift))[:2] for i in range(L)]
+        return {"sub0": {n: jnp.stack([v[j] for v in views]).astype(
+            pool["sub0"]["kv"].dtype) for j, n in enumerate(("k", "v"))}}
+
+    def solo(pool):
+        return T.decode_step(cfg, params, contiguous(pool),
+                             {"tokens": tokens}, jnp.int32(17),
+                             positions=jnp.asarray(positions))
+
+    # the step's own K/V, from the contiguous path over the incoming pool
+    _, first = solo(pool)
+    w = kv(pool)
+    for b in live:
+        lp = positions[b]
+        for j, n in enumerate(("k", "v")):
+            w[:, table[b, lp // BLOCK], lp % BLOCK, j] = np.asarray(
+                first["sub0"][n], np.float32)[:, b, 17]
+    written = {"sub0": {"kv": jnp.asarray(
+        w.reshape(pool["sub0"]["kv"].shape), pool["sub0"]["kv"].dtype)}}
+    ref_logits, _ = solo(written)
+
+    assert np.array_equal(kv(out)[:, 1:], kv(written)[:, 1:])
+    np.testing.assert_array_equal(np.asarray(logits)[live],
+                                  np.asarray(ref_logits)[live])

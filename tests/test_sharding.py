@@ -185,19 +185,17 @@ def test_rns_tp_auto_prefers_channels_then_columns_then_replicates():
 
 
 def test_cache_specs_paged_pool_sharding():
-    """Paged pools shard the independent physical-block axis (−4), never the
-    block contents — the dense rank-5 rule would split block_size, breaking
-    the pool's physical indexing."""
+    """Paged pools shard the independent physical-block axis, never the
+    block contents."""
     cfg = get_config("smollm-135m")
     pool = {"sub0": {
-        "k": jnp.zeros((2, 32, 16, 3, 8), jnp.float32),  # (L, n_phys, bs, Hk, dh)
-        "v": jnp.zeros((2, 32, 16, 3, 8), jnp.float32),
+        # (L, n_phys, bs, 2·Hk·dh): a token's K then V in one row
+        "kv": jnp.zeros((2, 32, 16, 2 * 3 * 8), jnp.float32),
         "pos": jnp.zeros((8,), jnp.int32),
     }}
     specs = cache_specs(MESH, cfg, pool, paged=True)
-    assert specs["sub0"]["k"] == P(None, ("data",), None, None, None)
-    assert specs["sub0"]["v"] == P(None, ("data",), None, None, None)
+    assert specs["sub0"]["kv"] == P(None, ("data",), None, None)
     assert specs["sub0"]["pos"] == P(None)
-    # dense rank-5 rule (paged=False) would have sequence-sharded axis 2:
+    # the dense rule (paged=False) knows no pool and would replicate it
     dense = cache_specs(MESH, cfg, pool, paged=False)
-    assert dense["sub0"]["k"] != specs["sub0"]["k"]
+    assert dense["sub0"]["kv"] != specs["sub0"]["kv"]

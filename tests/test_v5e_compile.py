@@ -200,3 +200,68 @@ def test_sharded_fused_decode_step_compiles_for_v5e_2x2(topo, one_chip,
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
     # the column layout's bitwise gather is a psum of scattered columns
     assert "all-reduce" in text
+
+
+POOL_SHAPES = re.compile(r"= \w+\[([\d,]*)\]\{[^}]*\} (copy|transpose|reshape|"
+                         r"dynamic-update-slice|dynamic-slice)\(")
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "rns-smollm-135m-fused"])
+def test_chunk_program_writes_the_pool_in_place_for_v5e(one_chip, monkeypatch,
+                                                        arch):
+    """The served decode chunk at the benchmark's size (32 slots, block 16,
+    1,153 blocks, 8 steps): no op copies, relayouts, slices or rewrites a
+    K/V pool, a layer's or the stacked one — each layer gathers from the
+    step's incoming pool and one scatter after the layer scan writes the
+    step's rows — and the donated pool is aliased to the returned one."""
+    import math
+
+    from repro.configs.base import get_config
+    from repro.core.rns_tensor import encode_params
+    from repro.models import transformer as T
+    from repro.serve.paged_cache import init_paged_cache
+    from repro.serve.scheduler import chunk_program
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config(arch)
+    slots, block, n_blocks, nlog = 32, 16, 1153, 36
+    spec = cfg.linear_spec
+
+    def params():
+        p = T.make_params(cfg, jax.random.PRNGKey(0))
+        return (encode_params(p, backend=spec.backend) if spec.encode_weights
+                else p)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p_shapes = placed(jax.eval_shape(params))
+    pool = placed(jax.eval_shape(
+        lambda: init_paged_cache(cfg, n_blocks, block, slots)))
+    text = chunk_program(cfg, 8).lower(
+        p_shapes, pool, arg((slots, nlog), jnp.int32),
+        *(arg((slots,), jnp.int32),) * 2, arg((slots,), jnp.bool_),
+        arg((slots,), jnp.int32), arg((slots, 2), jnp.uint32),
+        arg((), jnp.float32), arg((), jnp.int32)).compile().as_text()
+
+    # one layer's K or V, both, and the stacked pool of either
+    per_layer = n_blocks * block * cfg.num_kv_heads * cfg.head_dim
+    pool_sizes = {n * per_layer for n in (1, 2, cfg.n_blocks,
+                                          2 * cfg.n_blocks)}
+    bad = [m.group(0) for m in POOL_SHAPES.finditer(text)
+           if math.prod(int(d) for d in m.group(1).split(",") if d)
+           in pool_sizes]
+    assert not bad, bad
+    assert " scatter(" in text
+
+    # the pool leaves are parameters after the weights, outputs after cur
+    # and done: each is donated into its own output
+    first = len(jax.tree.leaves(p_shapes))
+    aliases = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, may-alias\)",
+                              text))
+    for i in range(len(jax.tree.leaves(pool))):
+        assert aliases.get(str(2 + i)) == str(first + i)
