@@ -10,14 +10,15 @@ are made and prepared as `serve.Engine` prepares them (encoded once where
 the configuration encodes them) and put in the scheduler's engine, whose
 compiled programs take the weights as an argument.  Each seed then runs
 the window's first call of that seed — the cell's own load — and compares
-the same sample a benchmark run compares.  The control is the reference
-computed at the configuration's lower precision (its ``control`` entry),
-read on the same prompts and served tokens.  The benchmark's own runs
-never run this.  Prints one JSON line per seed, then the lower reading
-(the largest of the program's) and the upper one (the smallest of the
-control's).  With ``--fault`` the program runs with that fault of
-`bench.faults` planted, in place of the control, and the last line gives
-the smallest reading of each number beside the cell's limit.
+the same sample a benchmark run compares, against the configuration's own
+reference.  The control is that reference computed at the configuration's
+lower precision (its ``control`` entry), read on the same prompts and
+served tokens.  The benchmark's own runs never run this.  Prints one JSON
+line per seed, then the lower reading (the largest of the program's) and
+the upper one (the smallest of the control's).  With ``--fault`` the
+program runs with that fault of `bench.faults` planted, in place of the
+control, and the last line gives the smallest reading of each number
+beside the cell's limit.
 """
 import argparse
 import json
@@ -65,6 +66,7 @@ def main(argv=None) -> int:
 
     spec = harness.resolve_cell(harness.load_benchmark(), args.workload)
     config, mix, limits = spec["config"], spec["mix"], spec["limits"]
+    ref = spec["reference"]
     device = harness.device_info(spec["cell"])
     if args.fault:
         faults.FAULTS[args.fault](setattr)
@@ -82,12 +84,13 @@ def main(argv=None) -> int:
         call = harness.Call(reqs, outs, dict(sched.stats),
                             time.perf_counter() - t)
         seqs = harness.sample([call], seed, limits["sample_requests"])
-        p = harness.gap_readings(config, seed, seqs, mix["slot_tokens"])
+        p = harness.gap_readings(ref, config, seed, seqs,
+                                 mix["slot_tokens"])
         prog.append(harness.numbers(p))
         line = {"seed": seed, "program": prog[-1], "program_per_request": p}
         if not args.fault:
-            c = harness.gap_readings(config, seed, seqs, mix["slot_tokens"],
-                                     lower)
+            c = harness.gap_readings(ref, config, seed, seqs,
+                                     mix["slot_tokens"], lower)
             ctrl.append(harness.numbers(c))
             line.update(control=ctrl[-1], control_per_request=c)
         line.update(failed=harness.count_failed([call],

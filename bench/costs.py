@@ -3,24 +3,14 @@
 These count the work the product needs, not the work an implementation
 does: residue channels, padded lanes and idle slots are not counted, so a
 change that removes them raises the share of the peak without making the
-count stale.  ``shape`` is `reference.shape_of` of a configuration.
+count stale.  What depends on the architecture comes from the
+configuration's reference ``ref``: its ``linears(shape)`` and
+``token_ops(shape, context)``; ``shape`` is its ``shape_of`` of the
+configuration, which gives the hidden size ``d`` and the ``vocab``.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
-
-
-def linears(shape: Dict) -> List[Tuple[int, int]]:
-    """(K, N) of the seven projections of one layer: q, k, v, o, gate, up,
-    down."""
-    d, hd, ff = shape["d"], shape["head_dim"], shape["ff"]
-    q, kv = shape["heads"] * hd, shape["kv_heads"] * hd
-    return [(d, q), (d, kv), (d, kv), (q, d), (d, ff), (d, ff), (ff, d)]
-
-
-def linear_params(shape: Dict) -> int:
-    """Weights of every layer's projections."""
-    return shape["layers"] * sum(k * n for k, n in linears(shape))
+from typing import Dict, Sequence, Tuple
 
 
 def head_ops(shape: Dict) -> int:
@@ -28,32 +18,26 @@ def head_ops(shape: Dict) -> int:
     return 2 * shape["d"] * shape["vocab"]
 
 
-def attention_ops(shape: Dict, context: int) -> int:
-    """Operations of one token's attention over ``context`` keys, all
-    layers: scores and the weighted sum, 2 · 2 · context · heads·head_dim."""
-    return (shape["layers"] * 4 * context * shape["heads"]
-            * shape["head_dim"])
-
-
-def request_ops(shape: Dict, prompt_len: int, new_tokens: int) -> int:
+def request_ops(ref, shape: Dict, prompt_len: int, new_tokens: int) -> int:
     """Operations one greedy request needs: the prompt's forward pass with
     the head at its last position, then one forward pass per further new
     token, each over the keys before it."""
-    per_token = 2 * linear_params(shape)
+    per_token = 2 * sum(k * n for k, n in ref.linears(shape))
     ops = prompt_len * per_token + head_ops(shape)
-    ops += sum(attention_ops(shape, t + 1) for t in range(prompt_len))
+    ops += sum(ref.token_ops(shape, t + 1) for t in range(prompt_len))
     for j in range(1, new_tokens):
         ops += per_token + head_ops(shape)
-        ops += attention_ops(shape, prompt_len + j)
+        ops += ref.token_ops(shape, prompt_len + j)
     return ops
 
 
-def served_ops(shape: Dict, prompt_lens: Sequence[int],
+def served_ops(ref, shape: Dict, prompt_lens: Sequence[int],
                new_lens: Sequence[int]) -> int:
-    return sum(request_ops(shape, p, n) for p, n in zip(prompt_lens, new_lens))
+    return sum(request_ops(ref, shape, p, n)
+               for p, n in zip(prompt_lens, new_lens))
 
 
-def megakernel_work(shape: Dict, counters: Dict) -> Dict[str, float]:
+def megakernel_work(ref, shape: Dict, counters: Dict) -> Dict[str, float]:
     """Logical int8 work of every projection routed through the megakernel.
 
     Rows are the real prompt tokens of each admission and one per emitting
@@ -61,7 +45,7 @@ def megakernel_work(shape: Dict, counters: Dict) -> Dict[str, float]:
     int8 weights once per forward pass (one per admission, one per decode
     step), the int8 activation rows in and the float32 rows out.
     """
-    shapes = linears(shape) * shape["layers"]
+    shapes = ref.linears(shape)
     rows = sum(counters["prompt_lens"]) \
         + counters["new_tokens"] - counters["requests"]
     passes = counters["requests"] + counters["chunks"] \

@@ -6,6 +6,11 @@ metric lives in a file of its own and is found by the name that
 
 * ``bench/configs/<config>.json``   the model as published, the program
   config it runs as, the serving deployment and the control's precision;
+  optionally ``"reference": "<module>"``, the file ``bench/<module>.py``
+  (without it, the Llama configurations' reference, `reference_of`), and
+  ``"program_keys"``, published keys beyond the nine Llama ones, each
+  with the ModelConfig field it sets;
+* ``bench/<module>.py``             the configuration's plain reference;
 * ``bench/traffic/<mix>.json``      read by the one generator, `traffic`;
 * ``bench/metrics/<metric>.py``     one reader, ``read(ctx)``, per metric;
 * ``bench/limits/<cell>.json``      the correctness limit of a cell;
@@ -15,9 +20,31 @@ A run builds the weights from the seed on the device, builds the program's
 `SlotScheduler`, warms the cell's own programs with one small call, then
 runs whole ``serve()`` calls back to back until ``seconds`` have passed.
 After the window it reads the device's memory peak, frees the program and
-checks a sample of the served tokens against the plain reference
-(`reference`).  With ``trace=True`` the first call of the window runs under
-the profiler and the per-layer metrics are read from it.
+checks a sample of the served tokens against the configuration's plain
+reference.  With ``trace=True`` the first call of the window runs under the
+profiler and the per-layer metrics are read from it.
+
+A reference module imports nothing of the program and provides:
+
+* ``shape_of(model)``: from the configuration file's published keys, a
+  dict of hashable values, among them the hidden size ``d`` and the
+  ``vocab`` (`costs.head_ops` reads those);
+* ``make_weights(model, weight_seed, dtype)``: its own weights, made from
+  the run's weight seed by the recipe the program follows;
+* ``logits(W, tokens, shape, lower=None)``: (B, S, vocab) float32 logits of
+  every position; ``shape`` is ``tuple(sorted(shape_of(model).items()))``
+  and ``lower`` the configuration's ``control`` as `control_lower` gives
+  it;
+* ``gaps(W, tokens, served, shape, lower=None)``: per position, the gap by
+  which the served token's logit (with ``lower``, the logit of the token
+  the lowered control puts first) lies below the reference's best, over
+  `pack`'s arrays;
+* ``linears(shape)``: the (K, N) of every projection in one token's
+  forward pass, over all layers, that the configuration's linear path
+  runs;
+* ``token_ops(shape, context)``: one token's operations beyond the
+  projections and the head: attention over ``context`` keys in the layers
+  that attend, and any per-token mixer work, such as a state update.
 """
 from __future__ import annotations
 
@@ -30,17 +57,18 @@ import sys
 import tempfile
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from bench import reference, traffic
 from bench import trace as tracelib
+from bench import traffic
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 
-# published (Hugging Face) key → the program's ModelConfig field
+# published (Hugging Face) key → the program's ModelConfig field, for every
+# configuration; a configuration's ``program_keys`` add to these
 _PROGRAM_KEYS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
                  "num_attention_heads": "num_heads",
                  "num_key_value_heads": "num_kv_heads",
@@ -62,27 +90,43 @@ def load_benchmark(root: Path = ROOT) -> Dict:
 
 
 def resolve_cell(bench: Dict, workload: str, root: Path = ROOT) -> Dict:
-    """The cell's entry, its configuration file and its traffic mix."""
+    """The cell's entry, its configuration file, its traffic mix, its
+    limits and its configuration's reference module."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise BenchError(f"no workload {workload!r}; have {sorted(cells)}")
     cell = cells[workload]
     entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
-    return {"cell": cell,
-            "config": json.loads((root / entry["file"]).read_text()),
+    config = json.loads((root / entry["file"]).read_text())
+    return {"cell": cell, "config": config,
+            "reference": reference_of(config, root),
             "mix": traffic.load(cell["traffic"], root / "bench"),
             "limits": json.loads((root / "bench" / "limits"
                                   / f"{workload}.json").read_text())}
 
 
-def metric_reader(name: str, root: Path = ROOT):
-    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    """``read(ctx)`` of ``bench/metrics/<name>.py``."""
+    return _load(root / "bench" / "metrics" / f"{name}.py",
+                 f"bench_metric_{name}").read
+
+
+def reference_of(config: Dict, root: Path = ROOT):
+    """The module ``bench/<reference>.py`` that the configuration names,
+    ``bench/reference.py`` where it names none."""
+    name = config.get("reference", "reference")
+    path = root / "bench" / f"{name}.py"
+    if not path.is_file():
+        raise BenchError(f"the configuration's reference bench/{name}.py "
+                         f"is not there")
+    return _load(path, f"reference_{name}")
 
 
 def per_layer_for(bench: Dict, workload: str) -> List[Dict]:
@@ -110,8 +154,9 @@ def peaks_for(kind: str, root: Path = ROOT) -> Dict:
 
 def program_config(config: Dict):
     """The program's ModelConfig: its registered config, with every
-    published size the file gives."""
-    from repro.configs.base import get_config
+    published size the file gives: the nine Llama keys, then those its
+    ``program_keys`` map to a field."""
+    from repro.configs.base import ModelConfig, get_config
 
     if config.get("hidden_act", "silu") != "silu":
         raise BenchError("the program's block applies silu only")
@@ -119,6 +164,15 @@ def program_config(config: Dict):
     over["head_dim"] = config.get("head_dim", config["hidden_size"]
                                   // config["num_attention_heads"])
     over["param_dtype"] = config["serving"]["dtype"]
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    for key, field in config.get("program_keys", {}).items():
+        if field not in fields:
+            raise BenchError(f"program_keys maps {key!r} to {field!r}, "
+                             f"which ModelConfig has not")
+        if key not in config:
+            raise BenchError(f"program_keys names {key!r}, which the "
+                             f"configuration does not give")
+        over[field] = config[key]
     return dataclasses.replace(get_config(config["program"]), **over)
 
 
@@ -303,20 +357,35 @@ def sample(calls: List[Call], seed: int, k: int) -> List[tuple]:
     return out
 
 
-def gap_readings(config: Dict, seed: int, seqs: List[tuple], length: int,
-                 lower=None) -> Dict[str, List[float]]:
-    """Per sampled sequence, the widest gap of a served token's reference
-    logit below the reference's best, the sum of the gaps and the number
-    of served tokens.  With ``lower`` the gaps are those of the token the
-    lowered control puts first."""
+def pack(sequences: Sequence[Tuple[Sequence[int], Sequence[int]]],
+         length: int):
+    """(tokens, served) arrays for a reference's ``gaps`` from (prompt, new
+    tokens) pairs, right-padded to ``length``."""
+    tokens = np.zeros((len(sequences), length), np.int32)
+    served = np.full((len(sequences), length), -1, np.int32)
+    for i, (prompt, new) in enumerate(sequences):
+        seq = list(prompt) + list(new)
+        if len(seq) > length:
+            raise ValueError(f"sequence of {len(seq)} tokens > {length}")
+        tokens[i, :len(seq)] = seq
+        served[i, len(prompt) - 1:len(seq) - 1] = new
+    return tokens, served
+
+
+def gap_readings(ref, config: Dict, seed: int, seqs: List[tuple],
+                 length: int, lower=None) -> Dict[str, List[float]]:
+    """Per sampled sequence, the widest gap of a served token's logit in
+    the reference ``ref`` below the reference's best, the sum of the gaps
+    and the number of served tokens.  With ``lower`` the gaps are those of
+    the token the lowered control puts first."""
     import jax
 
-    W = reference.make_weights(config, traffic.weight_seed(seed),
-                               config["serving"]["dtype"])
-    tokens, served = reference.pack(seqs, length)
-    shape = tuple(sorted(reference.shape_of(config).items()))
+    W = ref.make_weights(config, traffic.weight_seed(seed),
+                         config["serving"]["dtype"])
+    tokens, served = pack(seqs, length)
+    shape = tuple(sorted(ref.shape_of(config).items()))
     g = np.asarray(jax.device_get(
-        reference.gaps(W, tokens, served, shape, lower)), np.float64)
+        ref.gaps(W, tokens, served, shape, lower)), np.float64)
     return {"max": [float(v) for v in g.max(axis=1)],
             "sum": [float(v) for v in g.sum(axis=1)],
             "count": [int(v) for v in (served >= 0).sum(axis=1)]}
@@ -334,13 +403,15 @@ def control_lower(config: Dict):
     return (int(c["bits"]), tuple(c["matmuls"]))
 
 
-def check(config: Dict, limits: Dict, seed: int, calls: List[Call],
+def check(ref, config: Dict, limits: Dict, seed: int, calls: List[Call],
           slot_tokens: int, lower=None) -> Dict:
-    """The numbers compared, each with its value and its limit: those the
-    cell's limits file names, and the count of requests that failed.  With
-    ``lower`` the control stands in the program's place."""
+    """The numbers compared against the reference ``ref``, each with its
+    value and its limit: those the cell's limits file names, and the count
+    of requests that failed.  With ``lower`` the control stands in the
+    program's place."""
     seqs = sample(calls, seed, limits["sample_requests"])
-    got = numbers(gap_readings(config, seed, seqs, slot_tokens, lower))
+    got = numbers(gap_readings(ref, config, seed, seqs, slot_tokens,
+                               lower))
     out = {name: {"value": value, "limit": limits[name]["limit"]}
            for name, value in got.items() if name in limits}
     out["failed_requests"] = {"value": count_failed(
@@ -364,16 +435,18 @@ def call_counters(c: Call, serving: Dict) -> Dict:
             "new_lens": [r.max_new_tokens for r in c.reqs]}
 
 
-def trace_context(profile_dir: str, call: Call, config: Dict,
+def trace_context(tr: Dict, call: Call, config: Dict, ref,
                   peaks: Dict) -> Dict:
-    tr = tracelib.load(profile_dir)
+    """What a per-layer reader reads: the traced call's trace ``tr`` and
+    window, its counters, the configuration, its reference module
+    (``reference``) and that reference's shape of it (``model``)."""
     win = tracelib.window_of(tr, SPAN_CALL)
     return {"trace": tr, "window": win,
             "window_s": (win[1] - win[0]) * 1e-9,
             "busy_s": tracelib.busy_ns(tr, win) * 1e-9,
             "counters": call_counters(call, config["serving"]),
-            "model": reference.shape_of(config), "config": config,
-            "peaks": peaks}
+            "reference": ref, "model": ref.shape_of(config),
+            "config": config, "peaks": peaks}
 
 
 def read_per_layer(bench: Dict, workload: str, ctx: Dict,
@@ -401,6 +474,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     bench = load_benchmark(root)
     spec = resolve_cell(bench, workload, root)
     cell, config, mix = spec["cell"], spec["config"], spec["mix"]
+    ref = spec["reference"]
     device = device_info(cell, require_tpu)
     peaks = peaks_for(device["kind"], root)
     counter = CompileCounter()
@@ -438,7 +512,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                                                      for c in calls),
                   "failed": count_failed(calls, config["vocab_size"])}
         if trace:
-            ctx = trace_context(profile_dir, calls[0], config, peaks)
+            ctx = trace_context(tracelib.load(profile_dir), calls[0],
+                                config, ref, peaks)
             metrics = read_per_layer(bench, workload, ctx, root, log)
             device["busy_s"] = ctx["busy_s"]
             device["window_s"] = ctx["window_s"]
@@ -456,7 +531,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         if profile_dir:
             shutil.rmtree(profile_dir, ignore_errors=True)
 
-    checked = check(config, spec["limits"], seed, calls, mix["slot_tokens"])
+    checked = check(ref, config, spec["limits"], seed, calls,
+                    mix["slot_tokens"])
     result.update(correct=passed(checked), metrics=metrics, device=device,
                   compiles_in_window=counter.count, check=checked)
     return result

@@ -1,5 +1,7 @@
-"""Plain reference of the benchmark's decoder: a Llama-style block in
-float32 `jax.numpy`, with no kernels, cache or batching.
+"""The reference of the Llama configurations: a Llama-style block in
+float32 `jax.numpy`, with no kernels, cache or batching.  A configuration
+that names no ``reference`` is checked, and its work counted, by this
+module (the contract is `bench.harness`'s).
 
 It imports nothing of the program.  It reads the configuration file's
 published keys (Hugging Face names) and makes its own weights from the
@@ -24,7 +26,7 @@ weights per column): the control that must fail the comparison.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +45,22 @@ def shape_of(model: Dict) -> Dict:
             "ff": model["intermediate_size"], "vocab": model["vocab_size"],
             "eps": float(model["rms_norm_eps"]),
             "theta": float(model["rope_theta"])}
+
+
+def linears(shape: Dict) -> List[Tuple[int, int]]:
+    """(K, N) of every projection of one token's forward pass: q, k, v, o,
+    gate, up and down in every layer."""
+    d, hd, ff = shape["d"], shape["head_dim"], shape["ff"]
+    q, kv = shape["heads"] * hd, shape["kv_heads"] * hd
+    return [(d, q), (d, kv), (d, kv), (q, d), (d, ff), (d, ff),
+            (ff, d)] * shape["layers"]
+
+
+def token_ops(shape: Dict, context: int) -> int:
+    """Operations of one token's attention over ``context`` keys, all
+    layers: scores and the weighted sum, 2 · 2 · context · heads·head_dim."""
+    return (shape["layers"] * 4 * context * shape["heads"]
+            * shape["head_dim"])
 
 
 def _dense(key, d_in, d_out, dtype):
@@ -166,18 +184,3 @@ def gaps(W: Dict, tokens, served, shape: Tuple,
         pick = jnp.argmax(logits(W, tokens, shape, lower), axis=-1)
     got = jnp.take_along_axis(ref, pick[..., None], axis=-1)[..., 0]
     return jnp.where(served >= 0, best - got, 0.0)
-
-
-def pack(sequences: Sequence[Tuple[Sequence[int], Sequence[int]]],
-         length: int):
-    """(tokens, served) arrays for ``gaps`` from (prompt, new tokens) pairs,
-    right-padded to ``length``."""
-    tokens = np.zeros((len(sequences), length), np.int32)
-    served = np.full((len(sequences), length), -1, np.int32)
-    for i, (prompt, new) in enumerate(sequences):
-        seq = list(prompt) + list(new)
-        if len(seq) > length:
-            raise ValueError(f"sequence of {len(seq)} tokens > {length}")
-        tokens[i, :len(seq)] = seq
-        served[i, len(prompt) - 1:len(seq) - 1] = new
-    return tokens, served

@@ -21,13 +21,15 @@ def root(tmp_path_factory):
 def test_control_is_not_correct(root, cell, seed):
     spec = harness.resolve_cell(harness.load_benchmark(root), cell, root)
     config, mix, limits = spec["config"], spec["mix"], spec["limits"]
+    ref = spec["reference"]
     sched = harness.build(config, mix, seed)
     harness.warm(sched, mix, seed)
     calls = harness.window(sched, mix, seed, 0.0)["calls"]
     del sched
-    program = harness.check(config, limits, seed, calls, mix["slot_tokens"])
-    control = harness.check(config, limits, seed, calls, mix["slot_tokens"],
-                            harness.control_lower(config))
+    program = harness.check(ref, config, limits, seed, calls,
+                            mix["slot_tokens"])
+    control = harness.check(ref, config, limits, seed, calls,
+                            mix["slot_tokens"], harness.control_lower(config))
     assert harness.passed(program)
     assert not harness.passed(control)
     name = tiny.LIMITS[cell][0]

@@ -6,7 +6,7 @@ import shutil
 
 import pytest
 
-from bench import harness, traffic
+from bench import harness, reference, traffic
 from bench.harness import BENCH, ROOT
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -129,9 +129,38 @@ def test_peaks_name_their_source_and_unknown_chips_fail():
         harness.peaks_for("TPU v9 imaginary")
 
 
+# A later configuration's reference: the Llama reference with a state size
+# of its own, two projections a layer, attention in every fourth layer and
+# a state update of 2 · state · d operations a token in every layer.
+STAND_IN = """
+
+_llama_shape_of = shape_of
+
+
+def shape_of(model):
+    return dict(_llama_shape_of(model), state=model["mamba_d_state"])
+
+
+def linears(shape):
+    d = shape["d"]
+    return [(d, 2 * d), (2 * d, d)] * shape["layers"]
+
+
+def token_ops(shape, context):
+    return (shape["layers"] // 4 * 4 * context * shape["d"]
+            + shape["layers"] * 2 * shape["state"] * shape["d"])
+"""
+
+
 def test_a_new_cell_needs_only_files(tmp_path):
     """A later cell — fused-prefill on prefill_heavy — is a workloads entry
-    and a limits file: the harness resolves it, and its metrics, by name."""
+    and a limits file: the harness resolves it, and its metrics, by name.
+    A later configuration is a config file that names its reference and
+    the program sizes it sets, that reference's file, a limits file and
+    its configs and workloads entries: it resolves, sets the program's
+    sizes, and its per-layer metrics count with its own reference."""
+    from bench import trace
+
     shutil.copytree(BENCH, tmp_path / "bench")
     spec = json.loads(json.dumps(SPEC))
     spec["workloads"].append({"name": "fused-prefill",
@@ -150,3 +179,59 @@ def test_a_new_cell_needs_only_files(tmp_path):
     assert "megakernel_roofline" in names
     for n in names:
         assert callable(harness.metric_reader(n, tmp_path))
+
+    bench = tmp_path / "bench"
+    config = json.loads((bench / "configs"
+                         / "rns-smollm-135m-fused.json").read_text())
+    config.update(reference="stand_in_reference", mamba_d_state=128,
+                  program_keys={"mamba_d_state": "ssm_state"})
+    (bench / "configs" / "stand-in.json").write_text(json.dumps(config))
+    (bench / "stand_in_reference.py").write_text(
+        (bench / "reference.py").read_text() + STAND_IN)
+    (bench / "limits" / "stand-in-decode.json").write_text(
+        json.dumps({"sample_requests": 4, "max_logit_gap": {"limit": 1.0}}))
+    spec["configs"].append({"name": "stand-in", "source": "https://x.y/z",
+                            "file": "bench/configs/stand-in.json",
+                            "reduced": [], "why": "another architecture"})
+    spec["workloads"].append({"name": "stand-in-decode",
+                              "config": "stand-in",
+                              "traffic": "decode_heavy", "chips": 1,
+                              "why": "a state-space mixer"})
+    for m in spec["per_layer"]:
+        m["workloads"].append("stand-in-decode")
+    got = harness.resolve_cell(spec, "stand-in-decode", tmp_path)
+    ref = got["reference"]
+    assert ref.__file__ == str(bench / "stand_in_reference.py")
+    assert harness.program_config(got["config"]).ssm_state == 128
+
+    # one request of a 2-token prompt and one new token, read against the
+    # recorded v5e trace of a fused call
+    call = harness.Call([traffic.Req([5, 6], 1, 0)], [[5, 6, 7]],
+                        {"requests": 1, "new_tokens": 1, "chunks": 0}, 1.0)
+    tr = trace.read(str(ROOT / "tests" / "bench"
+                        / "v5e_fused_prefill_trace.json.gz"))
+    peaks = harness.peaks_for("TPU v5 lite", tmp_path)
+    ctx = harness.trace_context(tr, call, got["config"], ref, peaks)
+    read = {m["name"]: harness.metric_reader(m["name"], tmp_path)(ctx)
+            for m in harness.per_layer_for(spec, "stand-in-decode")}
+    # 30 layers of d 576: 2 · 30 · 2 · 576² weight ops a token; attention
+    # in 7 layers; 30 · 2 · 128 · 576 state ops a token
+    w = 30 * 2 * 2 * 576 ** 2
+    head = 2 * 576 * 49152
+
+    def extra(keys):
+        return 7 * 4 * keys * 576 + 30 * 2 * 128 * 576
+
+    ops = 2 * 2 * w + head + extra(1) + extra(2)
+    assert read["mfu"] == pytest.approx(
+        100 * ops / ctx["window_s"] / 393e12)
+    # rows: the 2 prompt tokens; one pass; Σ (K + 4·N) = 30 · 5 · 1728
+    kernel_s = 1e-9 * trace.total_ns(tr, trace.OPS_LINE, ctx["window"],
+                                     lambda n: trace.kind(n) == "_fused_call")
+    bound_s = max(2 * 2 * w / 393e12,
+                  (w + 2 * 30 * 5 * 1728) / 819e9)
+    assert read["megakernel_roofline"] == pytest.approx(
+        100 * bound_s / kernel_s)
+    llama = dict(ctx, reference=reference, model=reference.shape_of(config))
+    assert harness.metric_reader("mfu", tmp_path)(llama) != read["mfu"]
+    assert all(v is None or v >= 0 for v in read.values())

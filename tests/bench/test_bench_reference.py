@@ -66,9 +66,9 @@ def test_gap_of_the_reference_own_choice_is_zero(config):
         nxt = int(np.argmax(np.asarray(lg)))
         new.append(nxt)
         toks[0, t] = nxt
-    tokens, served = reference.pack([(prompt, new)], 16)
+    tokens, served = harness.pack([(prompt, new)], 16)
     g = np.asarray(reference.gaps(W, tokens, served, shape))
     assert g.max() == 0.0 and (served >= 0).sum() == len(new)
     wrong = [(t + 1) % config["vocab_size"] for t in new]
-    tokens, served = reference.pack([(prompt, wrong)], 16)
+    tokens, served = harness.pack([(prompt, wrong)], 16)
     assert np.asarray(reference.gaps(W, tokens, served, shape)).max() > 0
